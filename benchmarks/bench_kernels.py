@@ -20,7 +20,8 @@ from repro.utils import hw
 
 
 def _roofline_us(flops, bytes_):
-    return max(flops / hw.PEAK_FLOPS_BF16, bytes_ / hw.HBM_BW) * 1e6
+    chip = hw.peaks(hw.V5E)        # the chip these kernels are written for
+    return max(flops / chip.bf16_flops, bytes_ / chip.hbm_bytes_per_s) * 1e6
 
 
 def run(quick: bool = False) -> dict:

@@ -48,6 +48,8 @@ import math
 from dataclasses import dataclass, field
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
+from repro.analysis.vmem import kernel_name
+
 #: default allowed actual/ideal HBM-traffic ratio (see analysis.traffic);
 #: covers double-buffer ramp effects without hiding a real re-stream
 DEFAULT_TRAFFIC_FACTOR = 1.25
@@ -295,8 +297,7 @@ def analyze_eqn(eqn) -> KernelGridAnalysis:
     """Concretely evaluate every BlockSpec index map of one traced
     ``pallas_call`` equation over its full grid."""
     gm = eqn.params["grid_mapping"]
-    name = str(eqn.params.get("name_and_src_info",
-                              "pallas_call")).split(" at ")[0]
+    name = kernel_name(eqn)
     grid = tuple(int(g) for g in gm.grid)
     ka = KernelGridAnalysis(kernel=name, grid=grid,
                             n_points=int(math.prod(grid)) if grid else 1)
@@ -312,7 +313,7 @@ def analyze_eqn(eqn) -> KernelGridAnalysis:
     for i, bm in enumerate(gm.block_mappings):
         aval = getattr(bm.block_aval, "inner_aval", bm.block_aval)
         kind, idx = ("in", i) if i < n_in else ("out", i - n_in)
-        arr_shape = tuple(int(d) for d in bm.array_shape_dtype.shape)
+        arr_shape = tuple(int(d) for d in bm.array_aval.shape)
         blk_shape = tuple(int(d) for d in aval.shape)
         # blocks-per-dim in index-map coordinates: the index map emits one
         # coordinate per array dim, in units of the block shape

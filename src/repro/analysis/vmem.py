@@ -68,7 +68,7 @@ class VmemBuffer:
 class KernelFootprint:
     """The full VMEM bill of one ``pallas_call``."""
 
-    kernel: str                             # name_and_src_info string
+    kernel: str                             # see kernel_name()
     grid: Tuple[int, ...]
     buffers: List[VmemBuffer] = field(default_factory=list)
 
@@ -120,6 +120,17 @@ def iter_pallas_eqns(jaxpr, acc=None):
     return acc
 
 
+def kernel_name(eqn) -> str:
+    """The kernel function's name of one traced ``pallas_call`` equation
+    (e.g. ``"_encode_kernel"``): an explicit ``name=`` if the call gave one,
+    else the kernel jaxpr's source info."""
+    name = eqn.params.get("name")
+    if not name:
+        dbg = getattr(eqn.params["jaxpr"], "debug_info", None)
+        name = getattr(dbg, "func_src_info", None) or "pallas_call"
+    return str(name).split(" at ")[0]
+
+
 def footprint_of_eqn(eqn) -> KernelFootprint:
     """Read one traced ``pallas_call`` equation into a :class:`KernelFootprint`.
 
@@ -128,8 +139,7 @@ def footprint_of_eqn(eqn) -> KernelFootprint:
     plus the kernel jaxpr's trailing scratch refs.
     """
     gm = eqn.params["grid_mapping"]
-    name = str(eqn.params.get("name_and_src_info", "pallas_call")).split(" at ")[0]
-    fp = KernelFootprint(kernel=name, grid=tuple(gm.grid))
+    fp = KernelFootprint(kernel=kernel_name(eqn), grid=tuple(gm.grid))
 
     n_in, n_out = gm.num_inputs, gm.num_outputs
     for i, bm in enumerate(gm.block_mappings):

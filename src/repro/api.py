@@ -551,8 +551,7 @@ def render(model: DVNRModel, request: Optional[RenderRequest] = None, *,
 
     The old kwarg form ``render(model, eye=..., width=...)`` still renders
     identically but emits ``DeprecationWarning``."""
-    from repro.core.render import (_render_distributed,
-                                   _render_distributed_sampled)
+    from repro.serving.service import frame_operands, frame_program
 
     if model.parts_meta is None:
         raise ValueError("render() needs model.parts_meta (train via "
@@ -565,22 +564,27 @@ def render(model: DVNRModel, request: Optional[RenderRequest] = None, *,
     elif request is None:
         request = RenderRequest()
     r = request
-    b = backends.resolve(backend)
-    tf_table = r.tf.resolved_table()
+    view = None
     if cache is not None:
         view = cache.ensure(model, level=r.lod, timestep=r.timestep)
-        return _render_distributed_sampled(
-            view.pool, view.slots, view.grid_shape, view.brick_edge,
-            model.meta_arrays(), r.camera, r.width, r.height, model.grange,
-            n_samples=r.n_samples, impl=b, tf_table=tf_table,
-            density=r.tf.density, compute_dtype=r.compute_dtype,
-            out_dtype=r.out_dtype)
-    return _render_distributed(
-        model.cfg, model.stacked_params(), None, r.camera, r.width,
-        r.height, model.grange, mesh=mesh, n_samples=r.n_samples, impl=b,
-        tf_table=tf_table, density=r.tf.density,
+    # one frame through the render service's jitted frame program (a batch
+    # of one): the same compiled program a service tick of this shape runs
+    fn = frame_program(
+        model.cfg, fov=r.camera.fov_deg, width=r.width, height=r.height,
+        n_samples=r.n_samples, density=r.tf.density,
         compute_dtype=r.compute_dtype, out_dtype=r.out_dtype,
-        metas=model.meta_arrays())
+        backend=backends.resolve(backend),
+        view_geom=None if view is None else (view.grid_shape,
+                                             view.brick_edge))
+    cam = r.camera
+    pool, slots, params = frame_operands(view, model)
+    frames = fn(jnp.asarray([cam.eye], jnp.float32),
+                jnp.asarray([cam.center], jnp.float32),
+                jnp.asarray([cam.up], jnp.float32),
+                r.tf.resolved_table()[None], pool, slots,
+                model.meta_arrays(), jnp.asarray(model.grange, jnp.float32),
+                params)
+    return frames[0]
 
 
 def isosurface(model: DVNRModel, iso01=0.5, *, resolution: int = 32,

@@ -13,7 +13,10 @@ objects carrying capability metadata:
 - ``pallas_tpu`` compiled Pallas kernels (real TPU hardware)
 
 ``resolve("auto")`` picks the highest-priority backend available on the
-current jax platform: ``ref`` on CPU/GPU, ``pallas_tpu`` on TPU.
+current jax platform: ``ref`` everywhere, TPU included. ``pallas_tpu`` ranks
+below it because the v5e compiler still refuses its in-kernel table lookup,
+table-gradient scatter and uint32 sampling draws (``tests/test_tpu_compile.py``
+rehearses each one); it stays selectable by name.
 
 All dispatch helpers accept either a backend name or a ``Backend`` instance,
 so model objects and trainers can be parameterized by resolved backends and
@@ -55,7 +58,9 @@ class Backend:
     name: str
     kind: str                                     # "jnp" | "fused" | "pallas"
     description: str = ""
-    interpret: bool = True                        # pallas interpret mode
+    # pallas interpret mode: a pallas backend must state it (no default that
+    # could run interpreted on a TPU); jnp backends run no kernels -> False
+    interpret: Optional[bool] = None
     platforms: Tuple[str, ...] = ("cpu", "gpu", "tpu")
     priority: int = 0                             # rank for `auto` resolution
     capabilities: frozenset = field(default_factory=frozenset)
@@ -72,6 +77,13 @@ class Backend:
     # looser than vmem_limit_bytes. Overridable per cache; the closed-form
     # pool_bytes never exceeds it.
     cache_budget_bytes: int = 64 * 2**20
+
+    def __post_init__(self):
+        if self.interpret is None:
+            if self.kind == "pallas":
+                raise ValueError(f"pallas backend {self.name!r} must set "
+                                 "interpret=True or False")
+            object.__setattr__(self, "interpret", False)
 
     # ------------------------------------------------------------------ #
     @property
@@ -269,6 +281,6 @@ register_backend(Backend(
 register_backend(Backend(
     name="pallas_tpu", kind="pallas", interpret=False,
     description="compiled Pallas kernels on TPU hardware",
-    platforms=("tpu",), priority=100, capabilities=_ALL_OPS,
+    platforms=("tpu",), priority=2, capabilities=_ALL_OPS,
     vmem_limit_bytes=_TPU_VMEM_BYTES,
 ))

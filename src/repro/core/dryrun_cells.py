@@ -87,10 +87,12 @@ def _roofline_record(compiled, mesh, model_flops_global: float, meta: dict) -> d
     if isinstance(cost, list):
         cost = cost[0]
     an = analyze_hlo(compiled.as_text(), mesh.size)
+    # the dry run models the v5e it is sized for, whatever device it runs on
+    chip = hw.peaks(hw.V5E)
     terms = {
-        "compute_s": an.flops / hw.PEAK_FLOPS_BF16,
-        "memory_s": an.hbm_bytes / hw.HBM_BW,
-        "collective_s": an.collective_wire_bytes / hw.ICI_BW,
+        "compute_s": an.flops / chip.bf16_flops,
+        "memory_s": an.hbm_bytes / chip.hbm_bytes_per_s,
+        "collective_s": an.collective_wire_bytes / chip.ici_link_bytes_per_s,
     }
     dominant = max(terms, key=terms.get)
     mf_dev = model_flops_global / mesh.size
@@ -113,7 +115,7 @@ def _roofline_record(compiled, mesh, model_flops_global: float, meta: dict) -> d
         roofline=dict(terms, dominant=dominant,
                       step_time_s=max(terms.values()),
                       roofline_fraction=(
-                          mf_dev / hw.PEAK_FLOPS_BF16 / max(max(terms.values()), 1e-30))),
+                          mf_dev / chip.bf16_flops / max(max(terms.values()), 1e-30))),
         model_flops_global=model_flops_global,
         model_flops_per_device=mf_dev,
         useful_flops_ratio=mf_dev / max(an.flops, 1.0),

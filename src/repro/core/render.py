@@ -296,7 +296,7 @@ def binary_swap(mesh, axis_names, images, depths):
     global front-to-back order. For arbitrary (non-plane-separated) depth
     fields use ``composite_depth_sort``.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = int(np.prod([mesh.shape[a] for a in axis_names]))
@@ -309,7 +309,7 @@ def binary_swap(mesh, axis_names, images, depths):
     spec = P(axis_names)
     out, _ = shard_map(local, mesh=mesh,
                        in_specs=(spec, spec), out_specs=(spec, spec),
-                       check_rep=False)(images, depths)
+                       check_vma=False)(images, depths)
     return out
 
 
@@ -326,7 +326,7 @@ def make_distributed_render_step(cfg: DVNRConfig, mesh, *, n_samples: int = 64,
     vranges: (P,2) per-partition value ranges, grange: (2,) global range,
     origins/dirs: (R,3) replicated rays, tf_table: (K,4) replicated.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     axis_names = tuple(mesh.axis_names)
@@ -355,7 +355,7 @@ def make_distributed_render_step(cfg: DVNRConfig, mesh, *, n_samples: int = 64,
             local, mesh=mesh,
             in_specs=(spec_like(stacked_params), stacked, stacked, stacked,
                       rep, rep, rep, rep),
-            out_specs=stacked, check_rep=False,
+            out_specs=stacked, check_vma=False,
         )(stacked_params, parts_lo, parts_ext, vranges, origins, dirs,
           tf_table, grange)
 
@@ -372,8 +372,34 @@ def meta_arrays(parts_meta):
     return los, exts, vrs
 
 
-def _frame_from_rays(images, depths, width, height, out_dtype):
-    out = composite_depth_sort(images, depths)
+# Ray-march samples (partitions x rays x samples per ray) that one frame
+# program holds at once. TPU layouts pad the minor dim of every (..., 3),
+# (..., 4) or (..., F) per-sample array to 128 lanes, so a 512^2 frame with 64
+# samples per ray over 8 partitions needs ~64 GiB of HBM in one piece; in
+# chunks of this many samples each pass needs well under 1 GiB.
+_CHUNK_SAMPLES = 1 << 20
+
+
+def _composite_rays(partials, origins, dirs, n_partitions: int,
+                    n_samples: int):
+    """Depth-sort composite of every partition's partial image, (R, 4).
+
+    ``partials(origins, dirs) -> (images (P, r, 4), depths (P, r))`` renders
+    a batch of rays, here ray chunk by ray chunk of at most
+    ``_CHUNK_SAMPLES`` samples under ``jax.lax.map`` (one chunk for a small
+    frame); every ray is independent, so the chunking bounds memory without
+    changing what a ray computes."""
+    R = origins.shape[0]
+    per = min(R, max(1, _CHUNK_SAMPLES // (n_partitions * n_samples)))
+    n = -(-R // per)
+    pad = ((0, n * per - R), (0, 0))
+    o = jnp.pad(origins, pad, mode="edge").reshape(n, per, 3)
+    d = jnp.pad(dirs, pad, mode="edge").reshape(n, per, 3)
+    out = jax.lax.map(lambda od: composite_depth_sort(*partials(*od)), (o, d))
+    return out.reshape(n * per, -1)[:R]
+
+
+def _frame_from_rays(out, width, height, out_dtype):
     # contract: the image is f32 unless the caller explicitly asks otherwise —
     # a reduced compute_dtype must not leak into the returned frame
     out = out.astype(jnp.float32 if out_dtype is None else jnp.dtype(out_dtype))
@@ -395,24 +421,27 @@ def _render_distributed(cfg, stacked_params, parts_meta, cam: Camera,
     ``rays=(origins, dirs)`` likewise overrides camera ray generation — the
     render service's vmapped tick supplies traced per-client rays.
 
-    Peak memory for the ray-march intermediates is O(P * rays * n_samples) on
-    the single rendering device — fine for the host-side/compat path's small
-    partition counts; at production scale use ``make_distributed_render_step``,
-    which keeps one partition per device and binary-swap composites in place.
+    Ray-march intermediates are bounded by ``_CHUNK_SAMPLES`` per pass (see
+    :func:`_composite_rays`); across devices use
+    ``make_distributed_render_step``, which keeps one partition per device
+    and binary-swap composites in place.
     """
     tf_table = default_tf() if tf_table is None else tf_table
     backend = backends.resolve(impl)
     origins, dirs = make_rays(cam, width, height) if rays is None else rays
     los, exts, vrs = meta_arrays(parts_meta) if metas is None else metas
 
-    def one(params, lo, ext, vr):
-        return _render_partition(cfg, params, lo, ext, (vr[0], vr[1]), grange,
-                                 origins, dirs, tf_table,
-                                 n_samples=n_samples, density=density,
-                                 impl=backend, compute_dtype=compute_dtype)
+    def partials(origins, dirs):
+        def one(params, lo, ext, vr):
+            return _render_partition(
+                cfg, params, lo, ext, (vr[0], vr[1]), grange, origins, dirs,
+                tf_table, n_samples=n_samples, density=density, impl=backend,
+                compute_dtype=compute_dtype)
 
-    images, depths = jax.vmap(one)(stacked_params, los, exts, vrs)
-    return _frame_from_rays(images, depths, width, height, out_dtype)
+        return jax.vmap(one)(stacked_params, los, exts, vrs)
+
+    out = _composite_rays(partials, origins, dirs, los.shape[0], n_samples)
+    return _frame_from_rays(out, width, height, out_dtype)
 
 
 def _render_distributed_sampled(pool, slots, grid_shape, brick_edge: int,
@@ -432,14 +461,18 @@ def _render_distributed_sampled(pool, slots, grid_shape, brick_edge: int,
     origins, dirs = make_rays(cam, width, height) if rays is None else rays
     los, exts, vrs = metas
 
-    def one(slots_p, lo, ext, vr):
-        return _render_partition_sampled(
-            pool, slots_p, grid_shape, brick_edge, lo, ext, (vr[0], vr[1]),
-            grange, origins, dirs, tf_table, n_samples=n_samples,
-            density=density, impl=backend, compute_dtype=compute_dtype)
+    def partials(origins, dirs):
+        def one(slots_p, lo, ext, vr):
+            return _render_partition_sampled(
+                pool, slots_p, grid_shape, brick_edge, lo, ext,
+                (vr[0], vr[1]), grange, origins, dirs, tf_table,
+                n_samples=n_samples, density=density, impl=backend,
+                compute_dtype=compute_dtype)
 
-    images, depths = jax.vmap(one)(slots, los, exts, vrs)
-    return _frame_from_rays(images, depths, width, height, out_dtype)
+        return jax.vmap(one)(slots, los, exts, vrs)
+
+    out = _composite_rays(partials, origins, dirs, los.shape[0], n_samples)
+    return _frame_from_rays(out, width, height, out_dtype)
 
 
 # --------------------------------------------------------------------------- #

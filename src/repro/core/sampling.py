@@ -76,8 +76,13 @@ def key_words(key):
 
 
 def uniform01(bits):
-    """uint32 words -> f32 uniforms in [0, 1) (top 24 bits, exact in f32)."""
-    return (bits >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(1 / (1 << 24))
+    """uint32 words -> f32 uniforms in [0, 1) (top 24 bits, exact in f32).
+
+    The top 24 bits are below 2^24, so they pass through int32 unchanged and
+    convert to f32 exactly; the TPU kernel compiler has no uint32 -> f32
+    cast, and this route gives the same bits on every backend."""
+    top = (bits >> jnp.uint32(8)).astype(jnp.int32)
+    return top.astype(jnp.float32) * jnp.float32(1 / (1 << 24))
 
 
 def n_boundary(n_batch: int, boundary_lambda: float) -> int:

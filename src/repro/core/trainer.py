@@ -344,7 +344,7 @@ class DVNRTrainer:
         spmd_step = base_step
 
         if self.mesh is not None:
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
 
             axes = tuple(self.mesh.axis_names)
@@ -361,7 +361,7 @@ class DVNRTrainer:
                     in_specs=(spec_like(params), spec_like(opt), part, part,
                               part, part),
                     out_specs=(spec_like(params), spec_like(opt), part, part, part),
-                    check_rep=False,
+                    check_vma=False,
                 )(params, opt, vols, seeds, active, loss_ma)
 
             spmd_step = sharded

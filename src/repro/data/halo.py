@@ -75,7 +75,7 @@ def halo_exchange(vols: jnp.ndarray, grid: Tuple[int, int, int], mesh,
                   ghost: int = 1) -> jnp.ndarray:
     """shard_map ppermute halo exchange; vols stacked (P, ...) sharded over
     all mesh axes (one partition per device)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     g = ghost
@@ -103,4 +103,4 @@ def halo_exchange(vols: jnp.ndarray, grid: Tuple[int, int, int], mesh,
 
     spec = P(axes)
     return shard_map(local, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                     check_rep=False)(vols)
+                     check_vma=False)(vols)

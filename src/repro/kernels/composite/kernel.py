@@ -30,12 +30,12 @@ def _composite_kernel(rgba_ref, out_ref, acc_ref, trans_ref, *, n_s_blocks):
         acc_ref[...] = jnp.zeros_like(acc_ref)
         trans_ref[...] = jnp.ones_like(trans_ref)
 
-    rgba = rgba_ref[...]                       # (BR, BS, 4)
+    rgba = rgba_ref[...]                       # (BR, BS*4), lane-dense
     color = acc_ref[...]
     trans = trans_ref[...]
-    for s in range(rgba.shape[1]):             # static unroll within the block
-        a = rgba[:, s, 3:4]
-        color = color + trans * a * rgba[:, s, :3]
+    for s in range(rgba.shape[1] // 4):        # static unroll within the block
+        a = rgba[:, 4 * s + 3:4 * s + 4]
+        color = color + trans * a * rgba[:, 4 * s:4 * s + 3]
         trans = trans * (1.0 - a)
     acc_ref[...] = color
     trans_ref[...] = trans
@@ -48,16 +48,19 @@ def _composite_kernel(rgba_ref, out_ref, acc_ref, trans_ref, *, n_s_blocks):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def composite_pallas(rgba: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
+def composite_pallas(rgba: jnp.ndarray, *, interpret: bool) -> jnp.ndarray:
     R, S, _ = rgba.shape
     pr, ps = (-R) % BLOCK_R, (-S) % BLOCK_S
     rgba_p = jnp.pad(rgba, ((0, pr), (0, ps), (0, 0)))  # padded samples: a=0 (no-op)
     Rp, Sp = R + pr, S + ps
     n_s_blocks = Sp // BLOCK_S
+    # samples x rgba flattened onto the lane axis: a (BR, BS, 4) block would
+    # pad its 4-wide minor dim to 128 lanes (32x the VMEM)
+    rgba_p = rgba_p.reshape(Rp, Sp * 4)
     out = pl.pallas_call(
         functools.partial(_composite_kernel, n_s_blocks=n_s_blocks),
         grid=(Rp // BLOCK_R, n_s_blocks),
-        in_specs=[pl.BlockSpec((BLOCK_R, BLOCK_S, 4), lambda i, j: (i, j, 0))],
+        in_specs=[pl.BlockSpec((BLOCK_R, BLOCK_S * 4), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((BLOCK_R, 4), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Rp, 4), rgba.dtype),
         scratch_shapes=[pltpu.VMEM((BLOCK_R, 3), jnp.float32),

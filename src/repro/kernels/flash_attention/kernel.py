@@ -29,9 +29,6 @@ NEG_INF = -1e30
 BLOCK_Q = 256
 BLOCK_K = 256
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
@@ -92,7 +89,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 
 def flash_attention_bhsd(q, k, v, *, causal: bool = True, window=None,
-                         interpret: bool = True):
+                         interpret: bool):
     """q (B,Hq,Sq,dh); k,v (B,Hkv,Sk,dh) -> (B,Hq,Sq,dh)."""
     B, Hq, Sq, dh = q.shape
     _, Hkv, Sk, _ = k.shape
@@ -128,7 +125,7 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True, window=None,
             pltpu.VMEM((BLOCK_Q, 1), jnp.float32),
             pltpu.VMEM((BLOCK_Q, dh), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -64,7 +64,7 @@ def _pad(x, bn):
 
 @functools.partial(jax.jit, static_argnames=("interpret", "n_hidden"))
 def fused_mlp_fwd_pallas(x, w_in, w_hid, w_out, *, n_hidden: int,
-                         interpret: bool = True):
+                         interpret: bool):
     """x (N,D_in); w_in (D_in,W); w_hid (>=1,W,W); w_out (W,D_out) -> (N,D_out)."""
     xp, n = _pad(x, BLOCK_N)
     grid = (xp.shape[0] // BLOCK_N,)
@@ -86,7 +86,7 @@ def fused_mlp_fwd_pallas(x, w_in, w_hid, w_out, *, n_hidden: int,
 
 @functools.partial(jax.jit, static_argnames=("interpret", "n_hidden"))
 def fused_mlp_bwd_pallas(x, w_in, w_hid, w_out, g, *, n_hidden: int,
-                         interpret: bool = True):
+                         interpret: bool):
     xp, n = _pad(x, BLOCK_N)
     gp, _ = _pad(g, BLOCK_N)
     grid = (xp.shape[0] // BLOCK_N,)

@@ -306,7 +306,7 @@ def fused_train_step_pallas(coords, target, params, moments_m, moments_v,
                             masters, scalars, resolutions, *, n_hidden: int,
                             compute_dtype, beta1: float, beta2: float,
                             eps: float, weight_decay: float,
-                            interpret: bool = True):
+                            interpret: bool):
     """One fused train step for P stacked partitions (host-sampled batch).
 
     coords (P, N, 3) f32; target (P, N, D_out) f32; ``params`` / ``moments_m``
@@ -367,7 +367,7 @@ def fused_train_step_sampling_pallas(volumes, seeds, params, moments_m,
                                      sigma: float, ghost: int, n_hidden: int,
                                      compute_dtype, beta1: float, beta2: float,
                                      eps: float, weight_decay: float,
-                                     interpret: bool = True):
+                                     interpret: bool):
     """One fused train step for P stacked partitions, sampling INCLUDED.
 
     Instead of the host-sampled ``coords``/``target`` pair this variant takes
@@ -442,7 +442,7 @@ def fused_train_step_sampling_tiled_pallas(volumes, seeds, params, moments_m,
                                            n_hidden: int, compute_dtype,
                                            beta1: float, beta2: float,
                                            eps: float, weight_decay: float,
-                                           interpret: bool = True):
+                                           interpret: bool):
     """The sampling-included fused step with the volume TILED through VMEM.
 
     Same contract (state layout, seeds, returns, bit-exact draws/targets) as

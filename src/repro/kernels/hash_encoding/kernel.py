@@ -60,7 +60,7 @@ def _encode_kernel(res_ref, coords_ref, table_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def hash_encode_pallas(coords: jnp.ndarray, tables: jnp.ndarray,
-                       resolutions: jnp.ndarray, *, interpret: bool = True):
+                       resolutions: jnp.ndarray, *, interpret: bool):
     """coords (N,3) float32 in [0,1]; tables (L,T,F); resolutions (L,) int32.
 
     Returns (N, L*F) features. N is padded to BLOCK_N internally.

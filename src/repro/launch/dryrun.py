@@ -61,10 +61,12 @@ def run_cell(arch: str, shape: str, mesh_name: str, moe_dispatch: str = "scatter
     an = analyze_hlo(hlo, mesh.size)
 
     n = mesh.size
+    # the dry run models the v5e it is sized for, whatever device it runs on
+    chip = hw.peaks(hw.V5E)
     terms = {
-        "compute_s": an.flops / hw.PEAK_FLOPS_BF16,
-        "memory_s": an.hbm_bytes / hw.HBM_BW,
-        "collective_s": an.collective_wire_bytes / hw.ICI_BW,
+        "compute_s": an.flops / chip.bf16_flops,
+        "memory_s": an.hbm_bytes / chip.hbm_bytes_per_s,
+        "collective_s": an.collective_wire_bytes / chip.ici_link_bytes_per_s,
     }
     dominant = max(terms, key=terms.get)
     model_flops_per_dev = cell.meta["model_flops_global"] / n
@@ -89,7 +91,7 @@ def run_cell(arch: str, shape: str, mesh_name: str, moe_dispatch: str = "scatter
         roofline=dict(terms, dominant=dominant,
                       step_time_s=max(terms.values()),
                       roofline_fraction=(
-                          model_flops_per_dev / hw.PEAK_FLOPS_BF16 / max(max(terms.values()), 1e-30))),
+                          model_flops_per_dev / chip.bf16_flops / max(max(terms.values()), 1e-30))),
         model_flops_global=cell.meta["model_flops_global"],
         model_flops_per_device=model_flops_per_dev,
         useful_flops_ratio=model_flops_per_dev / max(an.flops, 1.0),

@@ -9,20 +9,13 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-try:  # jax >= 0.5: meshes carry explicit axis types
-    from jax.sharding import AxisType
 
-    def build_mesh(dev, axes) -> Mesh:
-        """Version-portable ``Mesh`` constructor (Auto axis types when
-        supported). ``dev``: ndarray of devices shaped like the mesh."""
-        return Mesh(dev, axes, axis_types=(AxisType.Auto,) * len(axes))
-
-except ImportError:  # jax 0.4.x: no axis_types argument
-    def build_mesh(dev, axes) -> Mesh:
-        """Version-portable ``Mesh`` constructor (jax 0.4.x fallback)."""
-        return Mesh(dev, axes)
+def build_mesh(dev, axes) -> Mesh:
+    """``Mesh`` with Auto axis types. ``dev``: ndarray of devices shaped like
+    the mesh."""
+    return Mesh(dev, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False, pods: int = 2):

@@ -170,7 +170,7 @@ def moe_block_a2a(cfg, p, x, sharder):
     processed per model-shard (the batch is replicated over "model" outside,
     so each model shard handles a 1/model_size slice of the token stream).
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     moe = cfg.moe
@@ -222,7 +222,7 @@ def moe_block_a2a(cfg, p, x, sharder):
                   P("model", None, None) if wg is not None else P(None),
                   P("model", None, None)),
         out_specs=(bspec, P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["wi"], wg if wg is not None else jnp.zeros((1,), x.dtype), p["wo"])
     return y, aux
 
@@ -235,7 +235,7 @@ def moe_block_tp(cfg, p, x, sharder):
     (E, C, D) capacity buffer (EXPERIMENTS.md §Perf, grok iteration 2).
 
     Gradient-exact vs moe_block_scatter (tests/test_moe_dispatch.py)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     moe = cfg.moe
@@ -283,7 +283,7 @@ def moe_block_tp(cfg, p, x, sharder):
         local, mesh=mesh,
         in_specs=(bspec, P(None, None), P(None, None, "model"), wg_spec,
                   P(None, "model", None)),
-        out_specs=(bspec, P()), check_rep=False,
+        out_specs=(bspec, P()), check_vma=False,
     )(x, p["router"], p["wi"], wg_arg, p["wo"])
 
 

@@ -7,7 +7,7 @@ compressed simulation state. This module serves that workload:
   (camera, transfer function, LOD, timestep) and get a ticket back;
 - each :meth:`RenderService.tick` coalesces every pending request into
   batches grouped by shape-static fields (width/height/fov/samples/LOD/
-  timestep/compute dtypes), renders each batch as ONE jitted program vmapped
+  timestep/compute dtypes), renders each batch as ONE jitted program mapped
   over the per-client camera + transfer-function arrays, and streams
   :class:`RenderResponse`\\ s back;
 - value samples come from the :class:`~repro.serving.cache.BrickCache` (warm
@@ -18,10 +18,11 @@ compressed simulation state. This module serves that workload:
 """
 from __future__ import annotations
 
+import functools
 import time
 from collections import OrderedDict
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional
+from typing import Any, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -38,9 +39,13 @@ def batched_frame_program(cfg, *, fov: float, width: int, height: int,
                           compute_dtype=None, out_dtype=None,
                           backend=None, cached: bool = True,
                           view_geom=None):
-    """The one-tick frame program: one frame per client, vmapped over the
+    """The one-tick frame program: one frame per client, mapped over the
     per-client camera (eye/center/up) and transfer-function arrays, sharing
-    the pool/slot-map/meta/param operands.
+    the pool/slot-map/meta/param operands. Frames run one after another
+    inside the program (``jax.lax.map``): each frame is already chunked into
+    passes large enough to fill the chip, so the tick's memory stays one
+    frame's whatever its batch (a vmap over clients multiplied it, and the
+    v5e compile of a vmapped cached frame took minutes).
 
     ``cached=True`` samples the :class:`BrickCache` pool (``view_geom`` =
     ``(grid_shape, brick_edge)`` of the cache view; ``params`` unused);
@@ -64,7 +69,37 @@ def batched_frame_program(cfg, *, fov: float, width: int, height: int,
             density=density, compute_dtype=compute_dtype,
             out_dtype=out_dtype, metas=metas, rays=rays)
 
-    return jax.vmap(one_frame, in_axes=(0, 0, 0, 0) + (None,) * 5)
+    def frames(eyes, centers, ups, tf_tables, pool, slots, metas, grange,
+               params):
+        return jax.lax.map(
+            lambda c: one_frame(*c, pool, slots, metas, grange, params),
+            (eyes, centers, ups, tf_tables))
+
+    return frames
+
+
+@functools.lru_cache(maxsize=64)
+def frame_program(cfg, *, fov: float, width: int, height: int,
+                  n_samples: int, density: float, compute_dtype=None,
+                  out_dtype=None, backend=None, view_geom=None):
+    """The jitted :func:`batched_frame_program` of one static configuration,
+    memoized so every tick of a shape group, and every ``api.render`` call
+    with the same shape, reuses one compiled program. ``view_geom`` given ->
+    the cached (brick-pool) program, ``None`` -> direct INR inference."""
+    return jax.jit(batched_frame_program(
+        cfg, fov=fov, width=width, height=height, n_samples=n_samples,
+        density=density, compute_dtype=compute_dtype, out_dtype=out_dtype,
+        backend=backend, cached=view_geom is not None, view_geom=view_geom))
+
+
+def frame_operands(view, model):
+    """The (pool, slots, params) operands of a :func:`frame_program` call:
+    the cache view's arrays on the cached program, the model's stacked
+    params on the direct one (the unused side gets placeholders)."""
+    if view is not None:
+        return view.pool, view.slots, None
+    return (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32),
+            model.stacked_params())
 
 
 @dataclass(frozen=True, eq=False)
@@ -82,7 +117,7 @@ class RenderResponse:
 
 class RenderService:
     """Coalesces concurrent :class:`repro.api.RenderRequest`\\ s into one
-    jitted vmapped render per tick, in front of a shared brick cache.
+    jitted render program per tick, in front of a shared brick cache.
 
     Construct with either a live ``model`` (a :class:`repro.api.DVNRModel`
     with ``parts_meta``) or a ``temporal`` :class:`TemporalModelCache` plus
@@ -126,7 +161,6 @@ class RenderService:
         self._pending: List[tuple] = []                    # (ticket, request)
         self._next_ticket = 0
         self._tick = 0
-        self._batch_fns: Dict[tuple, Any] = {}
         self.ticks: List[dict] = []
 
     # ------------------------------ models ------------------------------ #
@@ -184,34 +218,24 @@ class RenderService:
     @staticmethod
     def _group_key(req) -> tuple:
         # everything that fixes array shapes / static jit args; cameras and
-        # TF tables vary within a group (vmapped over)
+        # TF tables vary within a group (mapped over)
         tfk = req.tf.table_shape
         return (req.width, req.height, req.n_samples, req.camera.fov_deg,
                 req.lod, req.timestep, tfk, req.tf.density,
                 req.compute_dtype, req.out_dtype)
 
-    def _batch_fn(self, key, n: int, view):
-        """The jitted vmapped frame program of one group (memoized on the
-        group's static key + batch size + cache view shapes)."""
-        (W, H, S, fov, lod, _ts, _tfk, density, cdt, odt) = key
-        metas_shape = None if view is None else \
-            (view.grid_shape, view.brick_edge, view.slots.shape)
-        fn_key = (key[:5], key[6:], n, metas_shape)
-        fn = self._batch_fns.get(fn_key)
-        if fn is not None:
-            return fn
-        cached = view is not None
-        fn = jax.jit(batched_frame_program(
+    def _batch_fn(self, key, view):
+        """The jitted frame program of one group."""
+        (W, H, S, fov, _lod, _ts, _tfk, density, cdt, odt) = key
+        return frame_program(
             self.cfg, fov=fov, width=W, height=H, n_samples=S,
             density=density, compute_dtype=cdt, out_dtype=odt,
-            backend=self.backend, cached=cached,
-            view_geom=((view.grid_shape, view.brick_edge) if cached
-                       else None)))
-        self._batch_fns[fn_key] = fn
-        return fn
+            backend=self.backend,
+            view_geom=(None if view is None
+                       else (view.grid_shape, view.brick_edge)))
 
     def tick(self) -> List[RenderResponse]:
-        """Render every pending request (one jitted vmapped program per
+        """Render every pending request (one jitted program per
         group) and return the responses, submission-ordered."""
         from repro.core.render import default_tf
 
@@ -236,12 +260,9 @@ class RenderService:
             tfs = jnp.stack([(default_tf() if m[1].tf.table is None
                               else jnp.asarray(m[1].tf.table, jnp.float32))
                              for m in members])
-            fn = self._batch_fn(key, len(members), view)
+            fn = self._batch_fn(key, view)
             t0 = time.monotonic()
-            pool = view.pool if view is not None else jnp.zeros((), jnp.float32)
-            slots = view.slots if view is not None else \
-                jnp.zeros((), jnp.int32)
-            params = None if view is not None else model.stacked_params()
+            pool, slots, params = frame_operands(view, model)
             frames = fn(eyes, ctrs, ups, tfs, pool, slots, metas, grange,
                         params)
             frames = jax.block_until_ready(frames)

@@ -109,9 +109,8 @@ class HloAnalysis:
         return dict(agg)
 
 
-_OP_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*((?:\([^)]*\)|[\w\[\],{}\/ ]+?))\s+"
-    r"([\w\-]+)\((.*?)\)(.*)$")
+_NAME_RE = re.compile(r"%?([\w.\-]+)$")
+_OPCODE_RE = re.compile(r"([\w\-]+)\(")
 _COMP_RE = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*(?:\((.*?)\))?\s*->.*{\s*$")
 _PARAM_RE = re.compile(r"([\w.\-]+):\s*((?:\([^)]*\)|[\w\[\],{}]+))")
 _TRIP_RE = re.compile(r'known_trip_count\\?":\{\\?"n\\?":\\?"(\d+)')
@@ -143,15 +142,48 @@ def parse_hlo(text: str) -> Dict[str, Computation]:
             continue
         if cur is None:
             continue
-        mo = _OP_RE.match(line)
-        if not mo:
+        parsed = _parse_op_line(stripped)
+        if parsed is None:
             continue
-        name, type_str, opcode, operands_str, attrs = mo.groups()
+        name, type_str, opcode, operands_str = parsed
         operands = [o.strip().lstrip("%").split(" ")[0]
                     for o in _split_top_level(operands_str)]
         cur.ops[name] = Op(name, opcode, type_str.strip(), line, operands,
                            is_root=stripped.startswith("ROOT"))
     return comps
+
+
+def _close(s: str, start: int, stop: str) -> int:
+    """Index of the first ``stop`` char at bracket depth 0 from ``start``
+    (``len(s)`` if none). TPU layouts nest brackets inside a type, as in
+    ``f32[8,128]{1,0:T(8,128)}``."""
+    depth = 0
+    for i in range(start, len(s)):
+        ch = s[i]
+        if depth == 0 and ch in stop:
+            return i
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            depth -= 1
+    return len(s)
+
+
+def _parse_op_line(line: str):
+    """``[ROOT] name = TYPE opcode(operands), attrs`` ->
+    ``(name, type, opcode, operands)``, or None for any other line."""
+    if line.startswith("ROOT "):
+        line = line[5:]
+    lhs, sep, rhs = line.partition(" = ")
+    name = _NAME_RE.match(lhs.strip()) if sep else None
+    if name is None:
+        return None
+    end = _close(rhs, 0, " ")
+    mo = _OPCODE_RE.match(rhs, end + 1)
+    if mo is None:
+        return None
+    close = _close(rhs, mo.end(), ")")
+    return name.group(1), rhs[:end], mo.group(1), rhs[mo.end():close]
 
 
 def _split_top_level(s: str) -> List[str]:

@@ -1,10 +1,41 @@
-"""TPU v5e hardware constants used by the roofline analysis.
+"""Published per-chip peaks, keyed by ``jax.Device.device_kind``.
 
-``collective term`` divides per-chip wire bytes by a SINGLE ICI link's bandwidth
-(conservative: ring collectives on one mesh axis keep one link pair busy; a
-bidirectional ring would halve the term).
+A device kind missing from :data:`PEAKS` is an error, never a default: a
+roofline against the wrong chip's peaks is a wrong number, not an estimate.
+
+``ici_link_bytes_per_s`` is ONE ICI link (the roofline's collective term
+divides per-chip wire bytes by a single link: ring collectives on one mesh
+axis keep one link pair busy; a bidirectional ring would halve the term).
 """
-PEAK_FLOPS_BF16 = 197e12      # FLOP/s per chip
-HBM_BW = 819e9                # B/s per chip
-ICI_BW = 50e9                 # B/s per link
-CHIP_HBM_BYTES = 16 * 2**30   # v5e: 16 GiB
+from __future__ import annotations
+
+from dataclasses import dataclass
+
+
+@dataclass(frozen=True)
+class ChipPeaks:
+    bf16_flops: float             # FLOP/s per chip
+    hbm_bytes_per_s: float        # B/s per chip
+    ici_link_bytes_per_s: float   # B/s per ICI link
+    source: str
+
+
+#: the v5e's ``device_kind`` as JAX reports it
+V5E = "TPU v5 lite"
+
+PEAKS = {
+    V5E: ChipPeaks(
+        bf16_flops=197e12, hbm_bytes_per_s=819e9,
+        # 1,600 Gbit/s of chip-to-chip interconnect over 4 links
+        ici_link_bytes_per_s=50e9,
+        source="Google Cloud documentation, 'TPU v5e' (system architecture)"),
+}
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """The published peaks of ``device_kind``; raises for an unknown kind."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None

@@ -49,7 +49,7 @@ def test_max_level_caps_skip_expensive_checks():
 # --------------------------------------------------------------------------- #
 
 def test_zero_collectives_flags_psum():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.asarray(jax.devices()[:1]), ("x",))

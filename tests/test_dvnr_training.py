@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import api
 from repro.configs import dvnr as dvnr_cfg
 from repro.core.trainer import DVNRTrainer, adaptive_config, train_iterations
 from repro.data.volume import make_partition, partition_grid
@@ -49,36 +50,45 @@ def test_dvnr_training_converges():
 
 
 def test_boundary_loss_improves_boundary_accuracy():
-    """Paper Fig. 14: lambda > 0 improves cross-partition boundary agreement."""
+    """Paper Fig. 14: lambda > 0 improves cross-partition boundary agreement.
+
+    One seed's gap moves by more than the effect at 200 steps, so four
+    independent replicas of the 2-partition run (one trainer, 8 partitions:
+    each gets its own init and sample stream) train 600 steps, and the
+    mean gaps must differ by a clear margin."""
     parts, vols = _partition_volumes(grid=(2, 1, 1), local=(16, 16, 16))
+    n_rep = 4
 
     def run(lam):
         cfg = dvnr_cfg.SMOKE.replace(batch_size=2048, n_levels=3,
                                      log2_hashmap_size=10, n_neurons=16,
                                      n_hidden_layers=2, lrate=1e-2,
                                      boundary_lambda=lam)
-        tr = DVNRTrainer(cfg, n_partitions=2)
+        tr = DVNRTrainer(cfg, n_partitions=2 * n_rep)
         st = tr.init(jax.random.PRNGKey(0))
-        st, _ = tr.train(st, vols, steps=200, key=jax.random.PRNGKey(1))
+        st, _ = tr.train(st, jnp.concatenate([vols] * n_rep), steps=600,
+                         key=jax.random.PRNGKey(1))
         # evaluate on the shared boundary face (x=1 of part0 vs x=0 of part1)
-        from repro.core.inr import inr_apply
         yz = jnp.stack(jnp.meshgrid(jnp.linspace(0.01, 0.99, 24),
                                     jnp.linspace(0.01, 0.99, 24),
                                     indexing="ij"), -1).reshape(-1, 2)
         c0 = jnp.concatenate([jnp.full((yz.shape[0], 1), 1.0), yz], axis=1)
         c1 = jnp.concatenate([jnp.full((yz.shape[0], 1), 0.0), yz], axis=1)
-        p0 = jax.tree.map(lambda t: t[0], st.params)
-        p1 = jax.tree.map(lambda t: t[1], st.params)
-        v0 = inr_apply(cfg, p0, c0)
-        v1 = inr_apply(cfg, p1, c1)
-        # de-normalize to raw field values before comparing across partitions
-        r0 = v0 * (parts[0].vmax - parts[0].vmin) + parts[0].vmin
-        r1 = v1 * (parts[1].vmax - parts[1].vmin) + parts[1].vmin
-        return float(jnp.mean(jnp.square(r0 - r1)))
+        gaps = []
+        for r in range(n_rep):
+            p0 = jax.tree.map(lambda t: t[2 * r], st.params)
+            p1 = jax.tree.map(lambda t: t[2 * r + 1], st.params)
+            v0 = api.DVNRModel(cfg, p0).apply(c0)
+            v1 = api.DVNRModel(cfg, p1).apply(c1)
+            # de-normalize to raw field values before comparing partitions
+            r0 = v0 * (parts[0].vmax - parts[0].vmin) + parts[0].vmin
+            r1 = v1 * (parts[1].vmax - parts[1].vmin) + parts[1].vmin
+            gaps.append(float(jnp.mean(jnp.square(r0 - r1))))
+        return float(np.mean(gaps))
 
     gap_nolam = run(0.0)
     gap_lam = run(0.15)
-    assert gap_lam < gap_nolam, (gap_lam, gap_nolam)
+    assert gap_lam < 0.8 * gap_nolam, (gap_lam, gap_nolam)
 
 
 def test_weight_caching_warm_start_speeds_convergence():

@@ -16,7 +16,7 @@ SCRIPT = textwrap.dedent("""
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import build_mesh
     from repro.configs import dvnr as dvnr_cfg

@@ -182,7 +182,7 @@ def test_flash_attention_gqa_grid_discipline():
 
     q = SDS((1, 4, 512, 64), jnp.float32)
     kv = SDS((1, 2, 512, 64), jnp.float32)
-    rep = assert_clean(lambda q, k, v: flash_attention_bhsd(q, k, v),
+    rep = assert_clean(lambda q, k, v: flash_attention_bhsd(q, k, v, interpret=True),
                        q, kv, kv, checks=GRID_CHECKS)
     (kt,) = rep.result("hbm_traffic").details["traffic"]
     assert kt.intensity > 10                            # compute-bound regime

@@ -9,6 +9,7 @@ import textwrap
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core.render import composite_depth_sort, over
 
@@ -33,6 +34,44 @@ def test_depth_sort_reference_orders_by_depth():
     for p in (2, 1, 3, 0):
         ref = over(ref, imgs[p])
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
+
+
+@pytest.mark.parametrize("cached", [False, True], ids=["direct", "cached"])
+def test_ray_chunks_with_a_remainder_match_one_pass(monkeypatch, cached):
+    """A frame rendered in many ray chunks (the last one partial) equals the
+    same frame rendered in one pass, on the INR and the brick-pool paths."""
+    from repro.configs.dvnr import SMOKE
+    from repro.core import render
+    from repro.core.inr import init_inr
+
+    P, W, H, S = 4, 12, 10, 8
+    grid, edge = (8, 8, 8), 4
+    key = jax.random.PRNGKey(3)
+    params = jax.vmap(lambda k: init_inr(SMOKE, k))(jax.random.split(key, P))
+    los = jnp.asarray([(0.5 * (p % 2), 0.5 * (p // 2), 0.0)
+                       for p in range(P)], jnp.float32)
+    exts = jnp.tile(jnp.asarray([[0.5, 0.5, 1.0]], jnp.float32), (P, 1))
+    vrs = jnp.tile(jnp.asarray([[0.0, 1.0]], jnp.float32), (P, 1))
+    nb = grid[0] // edge
+    pool = jax.random.uniform(key, (P * nb ** 3,) + (edge + 1,) * 3)
+    slots = jnp.arange(P * nb ** 3, dtype=jnp.int32).reshape(P, nb, nb, nb)
+    cam = render.Camera(eye=(1.8, 1.4, 1.6))
+
+    def frame():
+        if cached:
+            return render._render_distributed_sampled(
+                pool, slots, grid, edge, (los, exts, vrs), cam, W, H,
+                (0.0, 1.0), n_samples=S)
+        return render._render_distributed(
+            SMOKE, params, None, cam, W, H, (0.0, 1.0), n_samples=S,
+            metas=(los, exts, vrs))
+
+    one_pass = np.asarray(frame())
+    # 7 rays per chunk: 120 rays -> 17 full chunks and one of a single ray
+    monkeypatch.setattr(render, "_CHUNK_SAMPLES", P * S * 7)
+    chunked = np.asarray(frame())
+    assert one_pass[..., 3].max() > 0
+    np.testing.assert_allclose(chunked, one_pass, rtol=0, atol=1e-6)
 
 
 _SWAP_SCRIPT = textwrap.dedent("""
