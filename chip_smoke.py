@@ -123,39 +123,21 @@ FULL = Sizes()
 # --------------------------------------------------------------------------- #
 # Measurement plumbing
 # --------------------------------------------------------------------------- #
-class CompileClock:
-    """Seconds spent in backend compiles (or persistent-cache reads), from
-    JAX's own monitoring events."""
+def compile_mark() -> int:
+    """A wall-clock mark for :func:`compiled_since`. Importing
+    ``repro.tracing`` registers the program's compile listeners."""
+    import repro.tracing  # noqa: F401
+    return time.time_ns()
 
-    EVENT = "/jax/core/compile/backend_compile_duration"
 
-    def __init__(self):
-        import jax
-        self.seconds, self.count, self.cache_hits = 0.0, 0, 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, event, duration, **_):
-        if event == self.EVENT:
-            self.seconds += duration
-            self.count += 1
-
-    def _on_event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def close(self):
-        import jax
-        jax.monitoring.unregister_event_duration_listener(self._on_duration)
-        jax.monitoring.unregister_event_listener(self._on_event)
-
-    def mark(self):
-        return (self.seconds, self.count, self.cache_hits)
-
-    def since(self, mark) -> dict:
-        s, n, h = mark
-        return {"compile_s": self.seconds - s, "compiles": self.count - n,
-                "cache_hits": self.cache_hits - h}
+def compiled_since(mark: int) -> dict:
+    """Backend compiles (or persistent-cache reads) that ended since
+    ``mark``: their seconds, their count, and the cache reads among them,
+    from ``repro.tracing``."""
+    from repro import tracing
+    log = tracing.compile_log(since_ns=mark)
+    return {"compile_s": sum(log), "compiles": len(log),
+            "cache_hits": tracing.cache_hits(mark)}
 
 
 def timed(fn):
@@ -199,7 +181,7 @@ def make_partitions(sizes: Sizes, seed: int):
             for p in range(sizes.ranks)]
 
 
-def phase_train(parts, sizes: Sizes, key, backend, clock, results):
+def phase_train(parts, sizes: Sizes, key, backend, results):
     """``api.train`` cold (compile + all chunks) and warm (one chunk, the
     built trainer reused); checks that the loss falls and every partition
     stays finite."""
@@ -211,9 +193,9 @@ def phase_train(parts, sizes: Sizes, key, backend, clock, results):
                          check_every=sizes.chunk, key=key, log_every=1,
                          trainer=trainer)
 
-    mark = clock.mark()
+    mark = compile_mark()
     (model, info), cold = timed(lambda: run(sizes.steps))
-    comp = clock.since(mark)
+    comp = compiled_since(mark)
     (_, info_w), warm = timed(lambda: run(sizes.chunk, info["trainer"]))
     losses = np.asarray([l for _, l in info["loss_history"]], np.float64)
     warm_losses = np.asarray([l for _, l in info_w["loss_history"]])
@@ -235,8 +217,7 @@ def phase_train(parts, sizes: Sizes, key, backend, clock, results):
     return model, losses
 
 
-def phase_parity(parts, sizes: Sizes, key, backend, ref_losses, clock,
-                 results):
+def phase_parity(parts, sizes: Sizes, key, backend, ref_losses, results):
     """The first ``parity_steps`` steps of the same training program on the
     host's CPU device; per-step mean losses must match the device run."""
     import jax
@@ -247,14 +228,14 @@ def phase_parity(parts, sizes: Sizes, key, backend, ref_losses, clock,
     cpu = jax.devices("cpu")[0]
     n = sizes.parity_steps
     vols = jax.device_put(jnp.stack([p.normalized() for p in parts]), cpu)
-    mark = clock.mark()
+    mark = compile_mark()
     with jax.default_device(cpu):
         (_, info), wall = timed(lambda: api.train(
             parts, sizes.cfg, backend=backend, steps=n, check_every=n,
             key=jax.device_put(key, cpu), volumes=vols, log_every=1))
     cpu_losses = np.asarray([l for _, l in info["loss_history"]], np.float64)
     rel = np.abs(cpu_losses - ref_losses[:n]) / np.abs(cpu_losses)
-    report("parity", {"steps": n, "cpu_s": wall, **clock.since(mark),
+    report("parity", {"steps": n, "cpu_s": wall, **compiled_since(mark),
                       "device_losses": ref_losses[:n].tolist(),
                       "cpu_losses": cpu_losses.tolist(),
                       "max_rel_diff": float(rel.max()),
@@ -289,13 +270,13 @@ def decoded_psnr(model, parts, sizes: Sizes) -> float:
     return float(psnr_from_mses(jnp.stack(mses)))
 
 
-def phase_compress(model, parts, sizes: Sizes, clock, results):
+def phase_compress(model, parts, sizes: Sizes, results):
     """``api.compress`` -> ``api.decompress``; the trained model appended to
     a ``TemporalModelCache``; decoded PSNR must clear the floor."""
     from repro import api
     from repro.core.temporal import TemporalModelCache
 
-    mark = clock.mark()
+    mark = compile_mark()
     (blobs, cinfo), t_comp = timed(lambda: api.compress(model))
     dec, t_dec = timed(lambda: api.decompress(sizes.cfg, blobs,
                                               parts_meta=model.parts_meta))
@@ -304,7 +285,8 @@ def phase_compress(model, parts, sizes: Sizes, clock, results):
     psnr_dec, t_psnr = timed(lambda: decoded_psnr(dec, parts, sizes))
     report("compress", {
         "compress_s": t_comp, "decompress_s": t_dec, "cache_append_s": t_app,
-        "psnr_cold_s": t_psnr, **clock.since(mark), "bytes": cinfo["bytes"],
+        "psnr_cold_s": t_psnr, **compiled_since(mark),
+        "bytes": cinfo["bytes"],
         "model_cr": cinfo["model_cr"], "cache_bytes": cache.total_bytes,
         "psnr_decoded_db": psnr_dec,
         "psnr_floor_db": sizes.psnr_floor_db}, results)
@@ -315,7 +297,7 @@ def phase_compress(model, parts, sizes: Sizes, clock, results):
     return dec, cache
 
 
-def phase_render(model, sizes: Sizes, backend, clock, results):
+def phase_render(model, sizes: Sizes, backend, results):
     """``api.render`` of one frame, cold and warm; the frame is finite and
     not empty."""
     import numpy as np
@@ -323,9 +305,9 @@ def phase_render(model, sizes: Sizes, backend, clock, results):
 
     req = api.RenderRequest(width=sizes.frame, height=sizes.frame,
                             n_samples=sizes.samples)
-    mark = clock.mark()
+    mark = compile_mark()
     _, cold = timed(lambda: api.render(model, req, backend=backend))
-    comp = clock.since(mark)
+    comp = compiled_since(mark)
     frame, warm = timed(lambda: api.render(model, req, backend=backend))
     frame = np.asarray(frame)
     report("render", {"frame": f"{sizes.frame}x{sizes.frame}",
@@ -338,7 +320,7 @@ def phase_render(model, sizes: Sizes, backend, clock, results):
     return frame
 
 
-def phase_serve(model, temporal, direct_frame, sizes: Sizes, backend, clock,
+def phase_serve(model, temporal, direct_frame, sizes: Sizes, backend,
                 results):
     """A ``RenderService`` over a ``BrickCache`` answers orbiting live
     requests plus one temporal-cache request per tick, over ``ticks``
@@ -371,10 +353,10 @@ def phase_serve(model, temporal, direct_frame, sizes: Sizes, backend, clock,
         for c in cams:
             svc.submit(req(camera=c))
         svc.submit(req(camera=cam.orbit(angles[0]), timestep=0))
-        mark = clock.mark()
+        mark = compile_mark()
         out, wall = timed(lambda: svc.tick())
         frames.append([r.frame for r in out])
-        ticks.append({"tick_s": wall, **clock.since(mark),
+        ticks.append({"tick_s": wall, **compiled_since(mark),
                       "responses": len(out)})
     stats = cache.stats()
     first_live = np.asarray(frames[0][0])
@@ -402,20 +384,16 @@ def phase_serve(model, temporal, direct_frame, sizes: Sizes, backend, clock,
 def run_one_chip(sizes: Sizes, seed: int, backend) -> dict:
     import jax
 
-    clock, results = CompileClock(), {}
-    try:
-        key = jax.random.PRNGKey(seed)
-        parts, t_data = timed(lambda: make_partitions(sizes, seed))
-        print(f"data: {sizes.ranks} ranks x {sizes.local}^3 (+1 ghost), "
-              f"generated in {t_data} s", flush=True)
-        model, losses = phase_train(parts, sizes, key, backend, clock,
-                                    results)
-        phase_parity(parts, sizes, key, backend, losses, clock, results)
-        _, temporal = phase_compress(model, parts, sizes, clock, results)
-        frame = phase_render(model, sizes, backend, clock, results)
-        phase_serve(model, temporal, frame, sizes, backend, clock, results)
-    finally:
-        clock.close()
+    results = {}
+    key = jax.random.PRNGKey(seed)
+    parts, t_data = timed(lambda: make_partitions(sizes, seed))
+    print(f"data: {sizes.ranks} ranks x {sizes.local}^3 (+1 ghost), "
+          f"generated in {t_data} s", flush=True)
+    model, losses = phase_train(parts, sizes, key, backend, results)
+    phase_parity(parts, sizes, key, backend, losses, results)
+    _, temporal = phase_compress(model, parts, sizes, results)
+    frame = phase_render(model, sizes, backend, results)
+    phase_serve(model, temporal, frame, sizes, backend, results)
     return results
 
 
@@ -473,15 +451,12 @@ def run_four_chips(sizes: Sizes, seed: int, backend) -> dict:
     check(sizes.ranks % 4 == 0, "ranks must split evenly over 4 chips")
     axes = ("data", "model")
     mesh = build_mesh(np.asarray(devices[:4]).reshape(2, 2), axes)
-    clock, results = CompileClock(), {}
-    try:
-        _four_chip_phases(sizes, seed, backend, mesh, axes, clock, results)
-    finally:
-        clock.close()
+    results = {}
+    _four_chip_phases(sizes, seed, backend, mesh, axes, results)
     return results
 
 
-def _four_chip_phases(sizes, seed, backend, mesh, axes, clock, results):
+def _four_chip_phases(sizes, seed, backend, mesh, axes, results):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -500,12 +475,12 @@ def _four_chip_phases(sizes, seed, backend, mesh, axes, clock, results):
                          steps=n, check_every=n, key=key, log_every=1,
                          trainer=trainer)
 
-    mark = clock.mark()
+    mark = compile_mark()
     (m1, i1), t1 = timed(lambda: train(None))
-    c1 = clock.since(mark)
-    mark = clock.mark()
+    c1 = compiled_since(mark)
+    mark = compile_mark()
     (m4, i4), t4 = timed(lambda: train(mesh))
-    c4 = clock.since(mark)
+    c4 = compiled_since(mark)
     _, t4w = timed(lambda: train(mesh, i4["trainer"]))
 
     def flat(params, p):
@@ -551,9 +526,9 @@ def _four_chip_phases(sizes, seed, backend, mesh, axes, clock, results):
     spec = NamedSharding(mesh, PartitionSpec(axes))
     pair_img, pair_dep = jax.device_put((pair_img, pair_dep), spec)
     swap = jax.jit(functools.partial(binary_swap, mesh, axes))
-    mark = clock.mark()
+    mark = compile_mark()
     swapped, t_swap = timed(lambda: swap(pair_img, pair_dep))
-    c_swap = clock.since(mark)
+    c_swap = compiled_since(mark)
     _, t_swap_w = timed(lambda: swap(pair_img, pair_dep))
     err = float(max(np.abs(np.asarray(swapped[d]) - np.asarray(ref)).max()
                     for d in range(4)))

@@ -51,7 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import backends
+from repro import backends, tracing
 from repro.configs.dvnr import DVNRConfig
 from repro.core.inr import _decode_grid, _inr_apply, init_inr
 from repro.core.metrics import psnr_from_mses
@@ -262,6 +262,7 @@ class DVNRTrainer:
         adam = self.adam if adam is None else adam
         compute_dtype = self._compute_dtype
 
+        @jax.named_scope(tracing.SAMPLE)
         def sample_batch(vol, seed):
             coords = training_coords_counter(seed, cfg.batch_size,
                                              cfg.boundary_lambda,
@@ -394,7 +395,7 @@ class DVNRTrainer:
             spmd_step = self._build_spmd_step(adam)
         P, guard = self.P, self.cfg.guard_nonfinite
 
-        def chunk(params, opt, vols, key, step0, active, loss_ma):
+        def dvnr_train_chunk(params, opt, vols, key, step0, active, loss_ma):
             def body(carry, i):
                 params, opt, active, loss_ma, finite = carry
                 seeds = step_seeds(key, step0 + i, P)
@@ -416,7 +417,7 @@ class DVNRTrainer:
                 finite = finite & jnp.stack(leaf_ok).all(axis=0)
             return params, opt, active, loss_ma, finite, losses
 
-        return chunk
+        return dvnr_train_chunk
 
     def _chunk_fn(self, n_steps: int, lr_scale: float = 1.0):
         """Jitted ``n_steps``-long scan of the SPMD step (cached per
@@ -495,10 +496,22 @@ class DVNRTrainer:
         n_steps = int(n_steps)
         params, opt, active, loss_ma, finite, losses = \
             self._chunk_fn(n_steps, lr_scale)(
-                state.params, state.opt, volumes, key, jnp.int32(state.step),
-                state.active, state.loss_ma)
+                *self._chunk_args(state, volumes, key))
         return DVNRState(params, opt, loss_ma, active,
                          state.step + n_steps, finite), losses
+
+    def chunk_program(self, state: DVNRState, volumes, n_steps: int, *, key):
+        """The compiled chunk that :meth:`train_chunk` runs on these
+        arguments (served from JAX's caches when that call compiled it). Its
+        ``as_text()`` names each instruction of a profiler trace and holds
+        the instruction's stage (:mod:`repro.tracing`) in its ``op_name``."""
+        return self._chunk_fn(int(n_steps)).lower(
+            *self._chunk_args(state, volumes, key)).compile()
+
+    @staticmethod
+    def _chunk_args(state: DVNRState, volumes, key):
+        return (state.params, state.opt, volumes, key, jnp.int32(state.step),
+                state.active, state.loss_ma)
 
     # -------------------------- drivers -------------------------------- #
     def train(self, state: DVNRState, volumes, *, steps: int, key,

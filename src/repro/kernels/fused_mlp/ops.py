@@ -10,7 +10,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro import backends
+from repro import backends, tracing
 from repro.kernels.fused_mlp import ref as _ref
 from repro.kernels.fused_mlp.kernel import fused_mlp_bwd_pallas, fused_mlp_fwd_pallas
 
@@ -36,11 +36,12 @@ def fused_mlp(x, weights, impl: backends.BackendLike = "ref", *,
     Pallas kernels run bf16 inputs without upcasting. ``compute_dtype`` casts
     activations and weights before the matmul stack (differentiable casts)."""
     backend = backends.resolve(impl)
-    if compute_dtype is not None:
-        dt = backend.require_dtype(compute_dtype)
-        x = x.astype(dt)
-        weights = [w.astype(dt) for w in weights]
-    return _fused_mlp(x, weights, backend)
+    with jax.named_scope(tracing.MLP):
+        if compute_dtype is not None:
+            dt = backend.require_dtype(compute_dtype)
+            x = x.astype(dt)
+            weights = [w.astype(dt) for w in weights]
+        return _fused_mlp(x, weights, backend)
 
 
 def vmem_footprint(x, weights, impl: backends.BackendLike = "pallas"):

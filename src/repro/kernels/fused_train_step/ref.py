@@ -16,6 +16,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.core.sampling import training_coords_counter
 from repro.data.volume import sample_trilinear
 from repro.kernels.fused_mlp.ops import fused_mlp
@@ -65,6 +66,7 @@ def train_step_sampling_ref(params, opt, volumes, seeds, gate,
     (pinned and brick-tiled).
     """
 
+    @jax.named_scope(tracing.SAMPLE)
     def sample(vol_p, seed_p):
         coords = training_coords_counter(seed_p, n_batch, boundary_lambda,
                                          sigma)

@@ -17,7 +17,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
-from repro import backends
+from repro import backends, tracing
 from repro.kernels.hash_encoding import ref as _ref
 from repro.kernels.hash_encoding.kernel import hash_encode_pallas
 
@@ -33,9 +33,10 @@ def hash_encode(coords, tables, resolutions: Sequence[int],
     *positions* need the mantissa.
     """
     backend = backends.resolve(impl)
-    if compute_dtype is not None:
-        tables = tables.astype(backend.require_dtype(compute_dtype))
-    return _hash_encode(coords, tables, resolutions, backend)
+    with jax.named_scope(tracing.ENCODE):
+        if compute_dtype is not None:
+            tables = tables.astype(backend.require_dtype(compute_dtype))
+        return _hash_encode(coords, tables, resolutions, backend)
 
 
 def vmem_footprint(coords, tables, resolutions: Sequence[int],

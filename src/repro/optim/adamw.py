@@ -20,6 +20,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
+
 
 @dataclass(frozen=True)
 class OptConfig:
@@ -131,19 +133,21 @@ class AdamW:
         casting — bf16 rounding never feeds back into the trajectory. Without
         a master this is exactly ``params + gate * update``.
         """
-        master = state.get("mw", params)
-        updates, state = self.update(grads, state, master)
-        if gate is None:
-            apply = lambda p, u: p + u
-        else:
-            apply = lambda p, u: p + (gate * u).astype(p.dtype)
-        master = jax.tree.map(apply, master, updates)
-        if "mw" in state:
-            state = {**state, "mw": master}
-            params = jax.tree.map(lambda w, p: w.astype(p.dtype), master, params)
-        else:
-            params = master
-        return params, state
+        with jax.named_scope(tracing.ADAM):
+            master = state.get("mw", params)
+            updates, state = self.update(grads, state, master)
+            if gate is None:
+                apply = lambda p, u: p + u
+            else:
+                apply = lambda p, u: p + (gate * u).astype(p.dtype)
+            master = jax.tree.map(apply, master, updates)
+            if "mw" in state:
+                state = {**state, "mw": master}
+                params = jax.tree.map(lambda w, p: w.astype(p.dtype), master,
+                                      params)
+            else:
+                params = master
+            return params, state
 
     @staticmethod
     def apply_updates(params, updates):
