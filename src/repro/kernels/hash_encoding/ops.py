@@ -1,9 +1,12 @@
 """jit'd wrapper for hash encoding: backend dispatch + custom VJP.
 
 Forward: Pallas kernel (TPU) or pure-jnp oracle (CPU / default).
-Backward: scatter-add of the blended cotangents into the 8 corners per level —
-expressed as ``.at[].add`` which XLA:TPU lowers to its native combining scatter
-(the CUDA analogue is atomicAdd; see DESIGN.md hardware-adaptation notes).
+Backward (every backend): :func:`table_grad` sorts each level's 8N corner
+indices once, carrying the weighted cotangents, sums each run of equal
+indices with a segmented scan in f32 and reads every table row's run total
+out by a search. A combining scatter of the 8N corner updates (the
+CUDA analogue is atomicAdd) is serial per update on a TPU, ~14–40 ns each
+(PERF.md, section 6).
 
 Dispatch goes through :mod:`repro.backends`; ``impl`` accepts a backend name
 (``"ref"``, ``"fused"``, ``"pallas"``, ``"pallas_tpu"``, ``"auto"``) or a
@@ -16,6 +19,7 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro import backends, tracing
 from repro.kernels.hash_encoding import ref as _ref
@@ -74,7 +78,7 @@ def _fwd_impl(coords, tables, resolutions, backend):
 def _fwd(coords, tables, resolutions, backend):
     if _use_fused(backend):
         # store the (small) corner indices/weights as residuals: the backward
-        # scatter reuses them instead of recomputing the whole index chain
+        # reuses them instead of recomputing the whole index chain
         # (EXPERIMENTS.md §Perf DVNR iteration C2)
         idx, ww = _ref.fused_corners(coords, resolutions, tables.shape[1])
         out = _ref._combine_fused(idx, ww, tables)
@@ -84,34 +88,108 @@ def _fwd(coords, tables, resolutions, backend):
 
 
 def _bwd(resolutions, backend, res, g):
-    coords, tshape, idx, ww = res
-    L, T, F = tshape
-    N = coords.shape[0]
-    if _use_fused(backend):
-        # level-vectorized combining scatter (one batched scatter-add)
-        gl = g.reshape(N, L, F).transpose(1, 0, 2)                # (L,N,F)
-        upd = ww.astype(g.dtype)[..., None] * gl[:, :, None, :]   # (L,N,8,F)
-        dt = jax.vmap(lambda i, u_: jnp.zeros((T, F), g.dtype)
-                      .at[i.reshape(-1)].add(u_.reshape(-1, F)))(idx, upd)
-        return jnp.zeros_like(coords), dt
+    coords, (_, T, _), idx, ww = res
+    if idx is None:
+        idx, ww = _ref.fused_corners(coords, resolutions, T)
+    return jnp.zeros_like(coords), table_grad(idx, ww, g, T)
 
-    g = g.reshape(N, L, F)
-    dt = jnp.zeros(tshape, g.dtype)
-    for l in range(L):
-        r = int(resolutions[l])
-        pos = coords * r
-        lo = jnp.clip(jnp.floor(pos), 0, max(r - 1, 0)).astype(jnp.int32)
-        w = pos - lo
-        for dx in (0, 1):
-            for dy in (0, 1):
-                for dz in (0, 1):
-                    corner = lo + jnp.array([dx, dy, dz], jnp.int32)
-                    idx = _ref.corner_indices(corner, r, T)
-                    ww = (jnp.where(dx, w[:, 0], 1 - w[:, 0])
-                          * jnp.where(dy, w[:, 1], 1 - w[:, 1])
-                          * jnp.where(dz, w[:, 2], 1 - w[:, 2]))
-                    dt = dt.at[l, idx].add(ww[:, None].astype(g.dtype) * g[:, l, :])
-    return jnp.zeros_like(coords), dt
+
+_COLUMNS = 128    # the sorted corners' segmented scan runs in 128 columns
+_STRIDE = 256     # keys per step of the row search's coarse pass
+
+
+def table_grad(idx, ww, g, table_size: int):
+    """Gradient of the ``(L, T, F)`` tables: each corner's weight times the
+    level's cotangent, summed per table row.
+
+    ``idx``, ``ww`` (L, N, 8) are the corners' indices and weights
+    (:func:`~repro.kernels.hash_encoding.ref.fused_corners`); ``g`` (N, L*F)
+    the encode's cotangent. Per level, the 8N indices are sorted once with
+    the F weighted-cotangent columns as payload (levels and any vmapped
+    ranks are batch dimensions of the one sort); a segmented scan sums each
+    run of equal indices, so no run's total passes through another's
+    partial sums; a search finds each row's run end, and a row that no
+    corner touches reads exactly 0. Every per-element array is (L, 8N),
+    lane-dense; sums in f32, returned in ``g``'s dtype.
+    """
+    L, N, C = idx.shape
+    F = g.shape[-1] // L
+    M = C * N
+    pad = ((0, 0), (0, -M % _STRIDE))          # past every row: sorts last
+    keys = jnp.pad(idx.transpose(0, 2, 1).reshape(L, M), pad,
+                   constant_values=table_size)                    # corner-major
+    w = ww.astype(jnp.float32).transpose(0, 2, 1)                 # (L,8,N)
+    gl = g.reshape(N, L, F).astype(jnp.float32).transpose(2, 1, 0)  # (F,L,N)
+    cols = tuple(jnp.pad((w * gl[f][:, None, :]).reshape(L, M), pad)
+                 for f in range(F))
+    keys, *cols = lax.sort((keys,) + cols, dimension=1, num_keys=1)
+    cols = _run_sums(keys, cols)
+    rows = jnp.arange(table_size, dtype=keys.dtype)
+    count = _count_at_most(keys, rows)                             # (L,T)
+    last = jnp.maximum(count - 1, 0)
+    hit = count > jnp.pad(count[:, :-1], ((0, 0), (1, 0)))        # a run at t
+    dt = jnp.stack([jnp.where(hit, jnp.take_along_axis(c, last, 1), 0.0)
+                    for c in cols], -1)                            # (L,T,F)
+    return dt.astype(g.dtype)
+
+
+def _run_sums(keys, cols):
+    """Inclusive segmented scan of the sorted (L, M) ``cols``: each element
+    gets its run's sum up to itself. The M elements are read as 128
+    columns of M/128 consecutive ones laid along the major axis, so the
+    scan shifts whole rows, never lanes; each column's last partial sum is
+    then scanned across the columns and carried into the next column's
+    leading run."""
+    L, M = keys.shape
+    R = M // _COLUMNS
+
+    def columns(x):
+        return x.reshape(L, _COLUMNS, R).transpose(0, 2, 1)       # (L,R,128)
+
+    k = columns(keys)
+    cols = _scan_runs(k, [columns(c) for c in cols], axis=1)
+    tails = _scan_runs(k[:, -1], [c[:, -1] for c in cols], axis=1)  # (L,128)
+    before = ((0, 0), (1, 0))
+    carry = k == jnp.pad(k[:, -1, :-1], before, constant_values=-1)[:, None]
+    cols = [c + jnp.where(carry, jnp.pad(t[:, :-1], before)[:, None], 0.0)
+            for c, t in zip(cols, tails)]
+    return [c.transpose(0, 2, 1).reshape(L, M) for c in cols]
+
+
+def _scan_runs(keys, cols, axis):
+    """Inclusive segmented scan along ``axis`` (Hillis-Steele): step d adds
+    the element d back wherever it holds the same key, i.e. the same run."""
+    n = keys.shape[axis]
+    widths = [(0, 0)] * keys.ndim
+    d = 1
+    while d < n:
+        same = (lax.slice_in_dim(keys, d, n, axis=axis)
+                == lax.slice_in_dim(keys, 0, n - d, axis=axis))
+        widths[axis] = (d, 0)
+        cols = [c + jnp.pad(jnp.where(same, lax.slice_in_dim(c, 0, n - d,
+                                                             axis=axis), 0.0),
+                            widths) for c in cols]
+        d *= 2
+    return cols
+
+
+def _count_at_most(keys, rows):
+    """keys (L, M) sorted along M, M a multiple of ``_STRIDE``; rows (T,) ->
+    (L, T): how many keys of each level are <= each row. Every
+    ``_STRIDE``-th key is compared with every row, and a binary search
+    finishes inside the one stride left: on a TPU each gather step costs
+    more than the comparisons it saves."""
+    M = keys.shape[1]
+    coarse = keys[:, _STRIDE - 1::_STRIDE]                         # (L,M/S)
+    count = _STRIDE * jnp.sum(coarse[:, None, :] <= rows[:, None], axis=-1,
+                              dtype=jnp.int32)
+    step = _STRIDE // 2
+    while step:
+        cand = count + step
+        probe = jnp.take_along_axis(keys, jnp.minimum(cand, M) - 1, 1)
+        count = jnp.where((cand <= M) & (probe <= rows), cand, count)
+        step //= 2
+    return count
 
 
 _hash_encode.defvjp(_fwd, _bwd)

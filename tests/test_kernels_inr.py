@@ -55,6 +55,90 @@ def test_hash_encode_grad_matches_ref():
     np.testing.assert_allclose(np.asarray(g_c), np.asarray(g_r), atol=1e-5)
 
 
+def _table_grad_np(coords, g, res, T):
+    """float64 ``np.add.at`` accumulation of the corners' weighted cotangents:
+    (grad (L,T,F), sum of |terms| per row (L,T,F), rows touched (L,T))."""
+    N = coords.shape[0]
+    L = len(res)
+    F = g.shape[1] // L
+    g = g.astype(np.float64).reshape(N, L, F)
+    grad = np.zeros((L, T, F))
+    mag = np.zeros((L, T, F))
+    touched = np.zeros((L, T), bool)
+    primes = [1, 2_654_435_761, 805_459_861]
+    for l, r in enumerate(res):
+        pos = coords * np.float32(r)                     # float32, as the op
+        lo = np.clip(np.floor(pos), 0, max(r - 1, 0)).astype(np.int64)
+        w = pos.astype(np.float64) - lo
+        for off in np.ndindex(2, 2, 2):
+            c = lo + np.asarray(off)
+            if (r + 1) ** 3 <= T:
+                idx = c[:, 0] + (r + 1) * (c[:, 1] + (r + 1) * c[:, 2])
+            else:
+                h = [(c[:, k] * primes[k]) % 2**32 for k in range(3)]
+                idx = (h[0] ^ h[1] ^ h[2]) % T
+            wc = np.prod(np.where(np.asarray(off) == 1, w, 1 - w), axis=1)
+            np.add.at(grad[l], idx, wc[:, None] * g[:, l])
+            np.add.at(mag[l], idx, np.abs(wc[:, None] * g[:, l]))
+            touched[l, idx] = True
+    return grad, mag, touched
+
+
+# (N, T, resolutions, table dtype, backend, ranks)
+_TABLE_GRAD_CASES = {
+    "dense": (500, 128, (2, 4), jnp.float32, "ref", None),
+    "hashed": (500, 64, (8, 16), jnp.float32, "ref", None),
+    "mixed": (500, 128, (2, 4, 16, 32), jnp.float32, "ref", None),
+    "duplicated": (4096, 64, (2, 3, 8, 32), jnp.float32, "ref", None),
+    "untouched": (300, 512, (4, 6, 64), jnp.float32, "ref", None),
+    "ranks": (300, 128, (2, 4, 16, 32), jnp.float32, "ref", 3),
+    "bf16": (500, 128, (2, 4, 16, 32), jnp.bfloat16, "ref", None),
+    "fused": (500, 128, (2, 4, 16, 32), jnp.float32, "fused", None),
+    "fused_ranks_bf16": (300, 128, (2, 4, 16, 32), jnp.bfloat16, "fused", 3),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_TABLE_GRAD_CASES))
+def test_table_grad_matches_float64_accumulation(case):
+    """The tables' gradient (sort, segmented sum, row readout) against a
+    float64 scatter-add: dense, hashed and mixed levels, 8N >> T, rows no
+    sample touches (exactly 0), a rank-vmapped call, bf16 tables, the ref
+    and fused backends."""
+    N, T, res, dtype, impl, ranks = _TABLE_GRAD_CASES[case]
+    P = ranks or 1
+    L, F = len(res), 4
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(7), 3)
+    # a sub-box of the domain, so that dense rows go untouched
+    coords = 0.6 * jax.random.uniform(k1, (P, N, 3))
+    tables = _mk_tables(k2, L, T, F, dtype)
+    tables = jnp.broadcast_to(tables, (P,) + tables.shape)
+    g = jax.random.normal(k3, (P, N, L * F)).astype(dtype)
+
+    def table_grad(c, t, gg):
+        _, vjp = jax.vjp(lambda t_: hash_encode(c, t_, res, impl), t)
+        return vjp(gg)[0]
+
+    if ranks:
+        got = jax.vmap(table_grad)(coords, tables, g)
+    else:
+        got = table_grad(coords[0], tables[0], g[0])[None]
+    assert got.dtype == dtype and got.shape == (P, L, T, F)
+    got = np.asarray(got.astype(jnp.float32), np.float64)
+    rel = 2.0 ** -8 if dtype == jnp.bfloat16 else 0.0
+    for p in range(P):
+        want, mag, touched = _table_grad_np(
+            np.asarray(coords[p]), np.asarray(g[p].astype(jnp.float32)),
+            res, T)
+        assert np.all(got[p][~touched] == 0.0)
+        err = np.abs(got[p] - want)
+        assert np.all(err <= rel * np.abs(want) + 1e-5 * mag + 1e-30), \
+            (case, p, float(err.max()))
+    if case == "untouched":
+        assert (~touched).any()
+    if case == "duplicated":
+        assert 8 * N >= 64 * T
+
+
 def test_hash_encode_boundary_coords():
     """Coords exactly at 0 and 1 must not index out of bounds."""
     coords = jnp.array([[0.0, 0.0, 0.0], [1.0, 1.0, 1.0], [0.0, 1.0, 0.5]])

@@ -4,7 +4,8 @@ topology (no chip attached; nothing runs).
 
 Every program that ``backend="auto"`` runs on a TPU must compile: the
 PRODUCTION256 train chunk (8 ranks of 256^3 on one chip), the 512^2 x 64
-direct frame, the cached service tick and the brick-cache decode. The
+direct frame, the cached service tick and the brick-cache decode; the
+train chunk's scan body holds no scatter (the tables' gradient sorts). The
 ``pallas_tpu`` kernels the compiler refuses are strict xfails carrying its
 refusal, so the change that makes one compile has to flip it — and may then
 let ``auto`` choose it.
@@ -14,6 +15,7 @@ one process may hold the TPU library, and only the worker given this file
 loads it.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -92,15 +94,48 @@ def test_peaks_keyed_by_the_v5e_device_kind(one_chip):
 # --------------------------------------------------------------------------- #
 # The auto path: every main-path program compiles
 # --------------------------------------------------------------------------- #
-def test_auto_train_chunk_compiles(one_chip, auto_tpu):
+@pytest.fixture(scope="module")
+def auto_train_chunk(one_chip, auto_tpu):
+    """The PRODUCTION256 64-step train chunk ``backend="auto"`` runs,
+    compiled once for the module."""
     from repro.core.trainer import DVNRTrainer
 
     tr = DVNRTrainer(PRODUCTION256, P, impl=auto_tpu, volume_shape=VOLUME)
-    compiled = jax.jit(tr._chunk_body(64), donate_argnums=(0, 1)).lower(
+    return jax.jit(tr._chunk_body(64), donate_argnums=(0, 1)).lower(
         *_on(one_chip, tr.abstract_chunk_args(64))).compile()
+
+
+def test_auto_train_chunk_compiles(auto_train_chunk, auto_tpu):
+    compiled = auto_train_chunk
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 10**9
     assert ("tpu_custom_call" in compiled.as_text()) == auto_tpu.is_pallas
+
+
+def _computations(text):
+    """{name: instruction lines} of a compiled HLO module's computations."""
+    return {m.group(1): m.group(2) for m in re.finditer(
+        r"^(?:ENTRY )?%(\S+) [^\n]*\{\n(.*?)\n\}$", text, re.M | re.S)}
+
+
+def test_auto_train_chunk_scan_body_holds_no_scatter(auto_train_chunk):
+    """The tables' gradient sorts each level's corner indices and sums the
+    runs: no scatter (of the per-corner updates or any other) is left in the
+    scan's body or in what it calls."""
+    comps = _computations(auto_train_chunk.as_text())
+    todo = [c for body in comps.values()
+            for c in re.findall(r"body=%([\w.\-]+)", body)]
+    assert todo, "no while loop in the chunk"
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name not in seen and name in comps:
+            seen.add(name)
+            todo += re.findall(r"%([\w.\-]+)", comps[name])
+    scatters = [line.strip()[:120] for name in seen
+                for line in comps[name].splitlines() if " scatter(" in line]
+    assert not scatters, scatters
+    assert any(" sort(" in comps[name] for name in seen)
 
 
 @pytest.mark.parametrize("cached", [False, True], ids=["direct", "cached"])

@@ -3,7 +3,8 @@
 Every step path (unfused, fused, fused with in-op sampling) carries the
 stage scopes of :mod:`repro.tracing` into the ``op_name`` of the compiled
 chunk's instructions: each stage is found in the scan's body, the hash
-encode both forward and under ``transpose(`` (the tables' gradient).
+encode both forward and under ``transpose(`` (the tables' gradient:
+its sort, segmented scan and row readout, with no scatter left).
 :func:`repro.tracing.compiles` counts a placement-only recompile, which does
 not retrace.
 """
@@ -70,6 +71,22 @@ def test_every_stage_holds_an_instruction_of_the_scan_body(program):
     for scope in tracing.STAGES:
         assert (scope, False) in found, (scope, found)
     assert (tracing.ENCODE, True) in found, found      # the tables' gradient
+
+
+def test_table_grad_runs_under_the_encode_transpose_without_scatter(program):
+    """The tables' gradient (sort, segmented scan by padded shifts, row
+    readout by gathers) carries ``dvnr.encode`` under ``transpose(``, so the
+    stage readers count it as ``table_grad``; no scatter of the per-corner
+    updates is left in the program."""
+    _, _, text = program
+    body = [op for op in _op_names(text) if "/while/body/" in op]
+    grad = {op.rsplit("/", 1)[-1] for op in body
+            if _innermost(op) == (tracing.ENCODE, True)}
+    assert {"sort", "pad", "gather"} <= grad, grad
+    sorts = [op for op in body if op.endswith("/sort")]
+    assert sorts and all(_innermost(op) == (tracing.ENCODE, True)
+                         for op in sorts), sorts
+    assert not re.search(r"= \S+ scatter\(", text)
 
 
 def test_no_instruction_names_a_scope_outside_the_list(program):
