@@ -193,16 +193,14 @@ def sample_trilinear(data: jnp.ndarray, coords01: jnp.ndarray, ghost: int = 1):
     lo = jnp.clip(jnp.floor(pos), 0, shape - 2).astype(jnp.int32)
     w = jnp.clip(pos - lo, 0.0, 1.0)
 
-    # single batched 8-corner gather (one linear-index take instead of 8
-    # advanced-index gathers; see EXPERIMENTS.md §Perf DVNR iteration)
+    # single batched 8-corner gather (one take instead of 8 advanced-index
+    # gathers; see EXPERIMENTS.md §Perf DVNR iteration), indexed in 3-D: a
+    # flat view of a tiled volume is a copy of it on a TPU
     off = jnp.asarray(np.stack(np.meshgrid([0, 1], [0, 1], [0, 1],
                                            indexing="ij"), -1).reshape(8, 3),
                       jnp.int32)
     corner = lo[:, None, :] + off[None]                       # (N,8,3)
-    nx, ny, nz = data.shape[:3]
-    lin = (corner[..., 0] * ny + corner[..., 1]) * nz + corner[..., 2]
-    flat = data.reshape(nx * ny * nz, *data.shape[3:])
-    vals = flat[lin.reshape(-1)].reshape(*lin.shape, *data.shape[3:])  # (N,8[,C])
+    vals = data[corner[..., 0], corner[..., 1], corner[..., 2]]  # (N,8[,C])
     wsel = jnp.where(off[None].astype(w.dtype) == 1,
                      w[:, None, :], 1.0 - w[:, None, :])      # (N,8,3)
     ww = wsel[..., 0] * wsel[..., 1] * wsel[..., 2]           # (N,8)

@@ -3,10 +3,11 @@
 Forward: Pallas kernel (TPU) or pure-jnp oracle (CPU / default).
 Backward (every backend): :func:`table_grad` sorts each level's 8N corner
 indices once, carrying the weighted cotangents, sums each run of equal
-indices with a segmented scan in f32 and reads every table row's run total
-out by a search. A combining scatter of the 8N corner updates (the
-CUDA analogue is atomicAdd) is serial per update on a TPU, ~14–40 ns each
-(PERF.md, section 6).
+indices with a segmented scan in f32 and reads the run totals out into the
+table's rows: by a search a row where the rows are no more than the
+corners, else by one write of each run's total at its row. A combining
+scatter of the 8N corner updates (the CUDA analogue is atomicAdd) is serial
+per update on a TPU, ~14–40 ns each (PERF.md, section 6).
 
 Dispatch goes through :mod:`repro.backends`; ``impl`` accepts a backend name
 (``"ref"``, ``"fused"``, ``"pallas"``, ``"pallas_tpu"``, ``"auto"``) or a
@@ -91,26 +92,34 @@ def _bwd(resolutions, backend, res, g):
     coords, (_, T, _), idx, ww = res
     if idx is None:
         idx, ww = _ref.fused_corners(coords, resolutions, T)
-    return jnp.zeros_like(coords), table_grad(idx, ww, g, T)
+    rows = tuple(min((int(r) + 1) ** 3, T) for r in resolutions)
+    return jnp.zeros_like(coords), table_grad(idx, ww, g, T, rows)
 
 
 _COLUMNS = 128    # the sorted corners' segmented scan runs in 128 columns
 _STRIDE = 256     # keys per step of the row search's coarse pass
 
 
-def table_grad(idx, ww, g, table_size: int):
+def table_grad(idx, ww, g, table_size: int, rows):
     """Gradient of the ``(L, T, F)`` tables: each corner's weight times the
     level's cotangent, summed per table row.
 
     ``idx``, ``ww`` (L, N, 8) are the corners' indices and weights
     (:func:`~repro.kernels.hash_encoding.ref.fused_corners`); ``g`` (N, L*F)
-    the encode's cotangent. Per level, the 8N indices are sorted once with
+    the encode's cotangent; ``rows`` each level's rows in use (a dense
+    level's (R+1)^3, else T). Per level, the 8N indices are sorted once with
     the F weighted-cotangent columns as payload (levels and any vmapped
     ranks are batch dimensions of the one sort); a segmented scan sums each
     run of equal indices, so no run's total passes through another's
-    partial sums; a search finds each row's run end, and a row that no
-    corner touches reads exactly 0. Every per-element array is (L, 8N),
-    lane-dense; sums in f32, returned in ``g``'s dtype.
+    partial sums. The readout (scope ``table_rows``) is chosen per level
+    from the shapes: a level with at most N rows in use, an eighth of its
+    corners, searches each row's run end (:func:`_read_rows`, ~16 gathers a
+    row); a level with more has each run's total written at its row
+    (:func:`_write_runs`, a scatter a column whose cost follows the 8N
+    corners and not the T rows). On a v5e a row's search costs about six
+    corners' writes (PERF.md, section 6).
+    A row that no corner touches reads exactly 0. Every per-element array
+    is (L, 8N), lane-dense; sums in f32, returned in ``g``'s dtype.
     """
     L, N, C = idx.shape
     F = g.shape[-1] // L
@@ -124,13 +133,75 @@ def table_grad(idx, ww, g, table_size: int):
                  for f in range(F))
     keys, *cols = lax.sort((keys,) + cols, dimension=1, num_keys=1)
     cols = _run_sums(keys, cols)
-    rows = jnp.arange(table_size, dtype=keys.dtype)
-    count = _count_at_most(keys, rows)                             # (L,T)
+    search = [l for l in range(L) if rows[l] <= N]
+    write = [l for l in range(L) if rows[l] > N]
+
+    def levels(x, ls):
+        return x if len(ls) == L else jnp.concatenate([x[l:l + 1] for l in ls])
+
+    with jax.named_scope(tracing.TABLE_ROWS):
+        parts = []
+        if search:
+            R = max(rows[l] for l in search)
+            dt = _read_rows(levels(keys, search),
+                            [levels(c, search) for c in cols], R)
+            if R < table_size:
+                dt = jnp.pad(dt, ((0, 0), (0, table_size - R), (0, 0)))
+            parts.append((search, dt))
+        if write:
+            parts.append((write, _write_runs(
+                levels(keys, write), [levels(c, write) for c in cols],
+                table_size)))
+        if len(parts) == 1:
+            dt = parts[0][1]
+        else:
+            at = {l: part[i] for ls, part in parts for i, l in enumerate(ls)}
+            dt = jnp.stack([at[l] for l in range(L)])              # (L,T,F)
+    return dt.astype(g.dtype)
+
+
+def _read_rows(keys, cols, n_rows: int):
+    """(L, n_rows, F): each of the first ``n_rows`` rows reads the total of
+    its run from the sorted, run-summed ``cols`` after a search for the
+    run's end (a gather a row and column): the cost follows the rows."""
+    rows = jnp.arange(n_rows, dtype=keys.dtype)
+    count = _count_at_most(keys, rows)                             # (L,R)
     last = jnp.maximum(count - 1, 0)
     hit = count > jnp.pad(count[:, :-1], ((0, 0), (1, 0)))        # a run at t
-    dt = jnp.stack([jnp.where(hit, jnp.take_along_axis(c, last, 1), 0.0)
-                    for c in cols], -1)                            # (L,T,F)
-    return dt.astype(g.dtype)
+    return jnp.stack([jnp.where(hit, jnp.take_along_axis(c, last, 1), 0.0)
+                      for c in cols], -1)
+
+
+def _write_runs(keys, cols, table_size: int):
+    """(L, T, F): each run's total, at the run's end in the sorted, run-summed
+    ``cols``, written at its row by one scatter a column. The runs' ends are
+    sorted to the front first (a second sort of the keys with the totals);
+    the places past the last run write spare rows past the level's, each
+    its own, which are cut off. The levels are laid end to end in one flat
+    index, so each scatter's rows are unique and ascending as written, and
+    only a vmapped rank, if any, is left for the compiler to fold in (it
+    folds several batch dimensions in the layout it picks, which need not
+    keep their order, and a scatter told its rows are sorted then writes
+    wrong rows: chip run). On a v5e a column's scatter costs half a value
+    what one scatter of (T, F) rows does (PERF.md, section 6)."""
+    nxt = jnp.pad(keys[:, 1:], ((0, 0), (0, 1)), constant_values=-1)
+    end = (keys != nxt) & (keys < table_size)                      # run ends
+    keys, *cols = lax.sort((jnp.where(end, keys, table_size),) + tuple(cols),
+                           dimension=1, num_keys=1)
+    L, M = keys.shape
+    span = table_size + M                       # a level's rows, then spares
+    place = lax.broadcasted_iota(keys.dtype, keys.shape, 1)
+    level = lax.broadcasted_iota(keys.dtype, keys.shape, 0)
+    rows = (level * span
+            + jnp.where(keys < table_size, keys, table_size + place))
+
+    def write(column):
+        out = jnp.zeros((L * span,), jnp.float32).at[rows.reshape(-1)].set(
+            column.reshape(-1), mode="promise_in_bounds", unique_indices=True,
+            indices_are_sorted=True)
+        return out.reshape(L, span)[:, :table_size]
+
+    return jnp.stack([write(c) for c in cols], -1)
 
 
 def _run_sums(keys, cols):

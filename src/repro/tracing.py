@@ -25,6 +25,9 @@ ENCODE = "dvnr.encode"          # hash-grid lookup (its transpose: table grad)
 MLP = "dvnr.mlp"                # the fused MLP, forward and backward
 ADAM = "dvnr.adam"              # the AdamW update
 STAGES = (SAMPLE, ENCODE, MLP, ADAM)
+# The tables' gradient's row readout, a sub-scope of the encode's transpose:
+# not ``dvnr.``, so its ops keep the innermost stage scope ``dvnr.encode``.
+TABLE_ROWS = "table_rows"
 
 CHUNK_PROGRAM = "dvnr_train_chunk"      # the scan-fused chunk's function name
 

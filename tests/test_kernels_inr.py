@@ -84,29 +84,39 @@ def _table_grad_np(coords, g, res, T):
     return grad, mag, touched
 
 
-# (N, T, resolutions, table dtype, backend, ranks)
+# (N, T, resolutions, F, table dtype, backend, ranks). A level with at most
+# N rows in use (a dense level's (R+1)^3, else T) searches each row's run;
+# one with more writes each run's total at its row.
 _TABLE_GRAD_CASES = {
-    "dense": (500, 128, (2, 4), jnp.float32, "ref", None),
-    "hashed": (500, 64, (8, 16), jnp.float32, "ref", None),
-    "mixed": (500, 128, (2, 4, 16, 32), jnp.float32, "ref", None),
-    "duplicated": (4096, 64, (2, 3, 8, 32), jnp.float32, "ref", None),
-    "untouched": (300, 512, (4, 6, 64), jnp.float32, "ref", None),
-    "ranks": (300, 128, (2, 4, 16, 32), jnp.float32, "ref", 3),
-    "bf16": (500, 128, (2, 4, 16, 32), jnp.bfloat16, "ref", None),
-    "fused": (500, 128, (2, 4, 16, 32), jnp.float32, "fused", None),
-    "fused_ranks_bf16": (300, 128, (2, 4, 16, 32), jnp.bfloat16, "fused", 3),
+    "dense": (500, 128, (2, 4), 4, jnp.float32, "ref", None),
+    "hashed": (500, 64, (8, 16), 4, jnp.float32, "ref", None),
+    "mixed": (500, 128, (2, 4, 16, 32), 4, jnp.float32, "ref", None),
+    "duplicated": (4096, 64, (2, 3, 8, 32), 4, jnp.float32, "ref", None),
+    "untouched": (300, 512, (4, 6, 64), 4, jnp.float32, "ref", None),
+    "ranks": (300, 128, (2, 4, 16, 32), 4, jnp.float32, "ref", 3),
+    "bf16": (500, 128, (2, 4, 16, 32), 4, jnp.bfloat16, "ref", None),
+    "fused": (500, 128, (2, 4, 16, 32), 4, jnp.float32, "fused", None),
+    "fused_ranks_bf16": (300, 128, (2, 4, 16, 32), 4, jnp.bfloat16, "fused",
+                         3),
+    "large_table": (40, 2048, (2, 4, 8, 16, 64), 8, jnp.float32, "ref",
+                    None),
+    "table_at_corners": (64, 512, (2, 8, 16), 8, jnp.float32, "ref", None),
+    "rows_at_batch": (125, 512, (4, 16), 8, jnp.float32, "ref", None),
+    "rows_past_batch": (124, 512, (4, 16), 8, jnp.float32, "ref", None),
+    "large_table_ranks": (40, 1024, (2, 8, 32), 8, jnp.float32, "fused", 3),
 }
 
 
 @pytest.mark.parametrize("case", sorted(_TABLE_GRAD_CASES))
 def test_table_grad_matches_float64_accumulation(case):
     """The tables' gradient (sort, segmented sum, row readout) against a
-    float64 scatter-add: dense, hashed and mixed levels, 8N >> T, rows no
-    sample touches (exactly 0), a rank-vmapped call, bf16 tables, the ref
-    and fused backends."""
-    N, T, res, dtype, impl, ranks = _TABLE_GRAD_CASES[case]
+    float64 scatter-add: dense, hashed and mixed levels, 8N >> T, T = 8N,
+    T >> 8N at F = 8, a level's rows in use at and past the batch (searched,
+    then written), rows no sample touches (exactly 0), rank-vmapped calls,
+    bf16 tables, the ref and fused backends."""
+    N, T, res, F, dtype, impl, ranks = _TABLE_GRAD_CASES[case]
     P = ranks or 1
-    L, F = len(res), 4
+    L = len(res)
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(7), 3)
     # a sub-box of the domain, so that dense rows go untouched
     coords = 0.6 * jax.random.uniform(k1, (P, N, 3))
@@ -114,12 +124,13 @@ def test_table_grad_matches_float64_accumulation(case):
     tables = jnp.broadcast_to(tables, (P,) + tables.shape)
     g = jax.random.normal(k3, (P, N, L * F)).astype(dtype)
 
+    @jax.jit
     def table_grad(c, t, gg):
         _, vjp = jax.vjp(lambda t_: hash_encode(c, t_, res, impl), t)
         return vjp(gg)[0]
 
     if ranks:
-        got = jax.vmap(table_grad)(coords, tables, g)
+        got = jax.jit(jax.vmap(table_grad))(coords, tables, g)
     else:
         got = table_grad(coords[0], tables[0], g[0])[None]
     assert got.dtype == dtype and got.shape == (P, L, T, F)
@@ -133,6 +144,15 @@ def test_table_grad_matches_float64_accumulation(case):
         err = np.abs(got[p] - want)
         assert np.all(err <= rel * np.abs(want) + 1e-5 * mag + 1e-30), \
             (case, p, float(err.max()))
+    # the readout, per level: a search where the rows in use are at most N,
+    # else one scatter of each run's total
+    rows = [min((r + 1) ** 3, T) for r in res]
+    jaxpr = str(jax.make_jaxpr(table_grad)(coords[0], tables[0], g[0]))
+    assert ("scatter" in jaxpr) == any(r > N for r in rows), case
+    if case.startswith("large_table"):
+        assert 8 * N < T and (~touched).any()
+        dense = [(r + 1) ** 3 <= T for r in res]
+        assert any(dense) and not all(dense)
     if case == "untouched":
         assert (~touched).any()
     if case == "duplicated":

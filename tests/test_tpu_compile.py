@@ -3,12 +3,15 @@ compiled by the chip's own compiler for one chip of a DESCRIBED v5e:2x2
 topology (no chip attached; nothing runs).
 
 Every program that ``backend="auto"`` runs on a TPU must compile: the
-PRODUCTION256 train chunk (8 ranks of 256^3 on one chip), the 512^2 x 64
-direct frame, the cached service tick and the brick-cache decode; the
-train chunk's scan body holds no scatter (the tables' gradient sorts). The
-``pallas_tpu`` kernels the compiler refuses are strict xfails carrying its
-refusal, so the change that makes one compile has to flip it — and may then
-let ``auto`` choose it.
+PRODUCTION256 train chunk (8 ranks of 256^3 on one chip), the ABLATION
+train chunk (4 ranks of 512^3 on one chip, within its 16 GB), the 512^2 x
+64 direct frame, the cached service tick and the brick-cache decode; the
+PRODUCTION256 chunk's scan body holds no scatter (the tables' gradient
+sorts and searches each row's run), the ABLATION chunk one scatter a
+table column, of unique, sorted rows (levels with more rows than samples).
+The ``pallas_tpu`` kernels the compiler refuses are strict xfails carrying
+its refusal, so the change that makes one compile has to flip it — and may
+then let ``auto`` choose it.
 
 The topology is described inside a module fixture (never at import): only
 one process may hold the TPU library, and only the worker given this file
@@ -23,7 +26,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import backends
-from repro.configs.dvnr import PRODUCTION256
+from repro.configs.dvnr import ABLATION, PRODUCTION256
 
 P = 8                                    # ranks of 256^3 on one chip
 VOLUME = (258, 258, 258)                 # 256^3 owned + 1 ghost layer
@@ -136,6 +139,36 @@ def test_auto_train_chunk_scan_body_holds_no_scatter(auto_train_chunk):
                 for line in comps[name].splitlines() if " scatter(" in line]
     assert not scatters, scatters
     assert any(" sort(" in comps[name] for name in seen)
+
+
+def test_auto_ablation_chunk_fits_one_chip(one_chip, auto_tpu):
+    """The ABLATION network's chunk at its published widths, 4 ranks of
+    512^3 on one chip (10 x 8 levels, T = 2^19 over 8N = 131,072 corners a
+    level): it fits the chip's 16 GB, and its tables' gradient writes the
+    run totals of the levels with more rows than samples with one scatter
+    a table column, of unique, sorted rows. The levels are one flat index,
+    and the compiler folds the vmapped rank into it rank-major, so the
+    rows stay in order: the program holds no sort of its own for them."""
+    from repro.core.trainer import DVNRTrainer
+
+    tr = DVNRTrainer(ABLATION, 4, impl=auto_tpu, volume_shape=(514,) * 3)
+    compiled = jax.jit(tr._chunk_body(4), donate_argnums=(0, 1)).lower(
+        *_on(one_chip, tr.abstract_chunk_args(4))).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 10**9
+    scatters = [line for line in compiled.as_text().splitlines()
+                if " scatter(" in line]
+    assert len(scatters) == ABLATION.n_features_per_level, scatters
+    for line in scatters:
+        assert "indices_are_sorted=true" in line
+        assert "unique_indices=true" in line
+    # the corners' sort and the run ends' sort, nothing sorted for a scatter
+    assert compiled.as_text().count(" sort(") == 2
+    spare = 2 ** ABLATION.log2_hashmap_size + 8 * ABLATION.batch_size
+    levels = sum((int(ABLATION.base_resolution * ABLATION.per_level_scale ** l)
+                  + 1) ** 3 > ABLATION.batch_size
+                 for l in range(ABLATION.n_levels))
+    assert f"constant({{{levels * spare}, 1}})" in compiled.as_text()
 
 
 @pytest.mark.parametrize("cached", [False, True], ids=["direct", "cached"])
