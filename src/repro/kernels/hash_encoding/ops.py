@@ -5,9 +5,9 @@ Backward (every backend): :func:`table_grad` sorts each level's 8N corner
 indices once, carrying the weighted cotangents, sums each run of equal
 indices with a segmented scan in f32 and reads the run totals out into the
 table's rows: by a search a row where the rows are no more than the
-corners, else by one write of each run's total at its row. A combining
-scatter of the 8N corner updates (the CUDA analogue is atomicAdd) is serial
-per update on a TPU, ~14–40 ns each (PERF.md, section 6).
+corners, else by tiles of rows that pick their runs' totals out of the
+sorted run ends with a one-hot product. Nothing is scattered: a TPU
+scatter is serial per value, ~5–40 ns each (PERF.md, section 6).
 
 Dispatch goes through :mod:`repro.backends`; ``impl`` accepts a backend name
 (``"ref"``, ``"fused"``, ``"pallas"``, ``"pallas_tpu"``, ``"auto"``) or a
@@ -98,6 +98,7 @@ def _bwd(resolutions, backend, res, g):
 
 _COLUMNS = 128    # the sorted corners' segmented scan runs in 128 columns
 _STRIDE = 256     # keys per step of the row search's coarse pass
+_TILE = 128       # rows a tile of the run totals' writer
 
 
 def table_grad(idx, ww, g, table_size: int, rows):
@@ -114,10 +115,12 @@ def table_grad(idx, ww, g, table_size: int, rows):
     partial sums. The readout (scope ``table_rows``) is chosen per level
     from the shapes: a level with at most N rows in use, an eighth of its
     corners, searches each row's run end (:func:`_read_rows`, ~16 gathers a
-    row); a level with more has each run's total written at its row
-    (:func:`_write_runs`, a scatter a column whose cost follows the 8N
-    corners and not the T rows). On a v5e a row's search costs about six
-    corners' writes (PERF.md, section 6).
+    row); a level with more sorts its run ends to the front and writes the
+    rows by tiles, each picking its runs' totals out of the sorted ends
+    with a one-hot product (:func:`_write_runs`, whose cost follows the
+    tiles' windows of ends, not a search a row). How many levels took each
+    is recorded as the function is traced
+    (:func:`repro.tracing.table_readouts`).
     A row that no corner touches reads exactly 0. Every per-element array
     is (L, 8N), lane-dense; sums in f32, returned in ``g``'s dtype.
     """
@@ -135,6 +138,7 @@ def table_grad(idx, ww, g, table_size: int, rows):
     cols = _run_sums(keys, cols)
     search = [l for l in range(L) if rows[l] <= N]
     write = [l for l in range(L) if rows[l] > N]
+    tracing.record_table_readout(len(search), len(write))
 
     def levels(x, ls):
         return x if len(ls) == L else jnp.concatenate([x[l:l + 1] for l in ls])
@@ -174,34 +178,73 @@ def _read_rows(keys, cols, n_rows: int):
 
 def _write_runs(keys, cols, table_size: int):
     """(L, T, F): each run's total, at the run's end in the sorted, run-summed
-    ``cols``, written at its row by one scatter a column. The runs' ends are
-    sorted to the front first (a second sort of the keys with the totals);
-    the places past the last run write spare rows past the level's, each
-    its own, which are cut off. The levels are laid end to end in one flat
-    index, so each scatter's rows are unique and ascending as written, and
-    only a vmapped rank, if any, is left for the compiler to fold in (it
-    folds several batch dimensions in the layout it picks, which need not
-    keep their order, and a scatter told its rows are sorted then writes
-    wrong rows: chip run). On a v5e a column's scatter costs half a value
-    what one scatter of (T, F) rows does (PERF.md, section 6)."""
+    ``cols``, at its row, by :func:`_write_tiles`. The runs' ends are sorted
+    to the front first (a second sort of the keys with the totals): a
+    level's run ends are then unique, ascending rows, each read once. The
+    levels are written one after another, so that only one level's windows
+    are held at a time."""
     nxt = jnp.pad(keys[:, 1:], ((0, 0), (0, 1)), constant_values=-1)
     end = (keys != nxt) & (keys < table_size)                      # run ends
     keys, *cols = lax.sort((jnp.where(end, keys, table_size),) + tuple(cols),
                            dimension=1, num_keys=1)
-    L, M = keys.shape
-    span = table_size + M                       # a level's rows, then spares
-    place = lax.broadcasted_iota(keys.dtype, keys.shape, 1)
-    level = lax.broadcasted_iota(keys.dtype, keys.shape, 0)
-    rows = (level * span
-            + jnp.where(keys < table_size, keys, table_size + place))
+    return lax.map(lambda level: _write_tiles(*level, table_size),
+                   (keys, jnp.stack(cols, 1)))
 
-    def write(column):
-        out = jnp.zeros((L * span,), jnp.float32).at[rows.reshape(-1)].set(
-            column.reshape(-1), mode="promise_in_bounds", unique_indices=True,
-            indices_are_sorted=True)
-        return out.reshape(L, span)[:, :table_size]
 
-    return jnp.stack([write(c) for c in cols], -1)
+def _write_tiles(keys, totals, table_size: int):
+    """(T, F) rows of one level from ``keys`` (M,), its rows unique and
+    ascending, then ``table_size`` in the places past its last, and their
+    ``totals`` (F, M): each key's totals at its row, 0 in every other.
+
+    The rows are written by tiles of ``_TILE``. A tile's keys are at most
+    ``_TILE`` from its first (the count of keys below its first row, by
+    :func:`_count_at_most`), so they lie in the two aligned blocks of
+    ``_TILE`` entries from the one that holds it. Each tile reads those two
+    blocks (whole blocks: a gather of aligned slices) and picks its rows out
+    of them by a one-hot product, so nothing is scattered and the cost
+    follows the tiles; a key outside the tile, or ``table_size``, matches no
+    row of it. The product is exact: each total is split into three bf16
+    parts that lie along the contraction, so each row accumulates its
+    total's parts, times an exact 1, in f32. The rows come out F-major by
+    tile, as the tables are laid out on a TPU."""
+    F, M = totals.shape
+    B = _TILE
+    n = -(-table_size // B)
+    nb = M // B + 2                        # blocks of entries, the last spare
+    first = jnp.arange(n, dtype=keys.dtype) * B
+    block = _count_at_most(keys[None], first - 1)[0] // B          # (n,)
+    keys = jnp.pad(keys, (0, nb * B - M),
+                   constant_values=table_size).reshape(nb, B)
+    totals = jnp.pad(totals, ((0, 0), (0, nb * B - M)))
+    parts = _bf16_parts(totals.reshape(F, nb, B).transpose(1, 0, 2))
+
+    def windows(x):
+        return jax.vmap(lambda b: lax.dynamic_slice_in_dim(x, b, 2))(block)
+
+    k = windows(keys)                                              # (n,2,B)
+    v = windows(parts)                                             # (n,2,3,F,B)
+    row = first[:, None] + jnp.arange(B, dtype=keys.dtype)         # (n,B)
+    # the keys broadcast over the parts before the compare, so the compiler
+    # makes the one-hot inside the product instead of writing it out
+    k = jnp.broadcast_to(k[:, :, None, :, None], (n, 2, 3, B, 1))
+    onehot = (k == row[:, None, None, None, :]).astype(jnp.bfloat16)
+    rows = jnp.einsum("tjpfe,tjper->tfr", v, onehot,
+                      preferred_element_type=jnp.float32)          # (n,F,B)
+    return rows.transpose(1, 0, 2).reshape(F, n * B)[:, :table_size].T
+
+
+def _bf16_parts(x):
+    """f32 ``x`` (..., F, B) -> bf16 (..., 3, F, B): three parts, high first,
+    whose sum in f32 is ``x`` exactly, in any order. Each part is what is
+    left with the low 16 bits of the word cut off, so no conversion
+    rounds."""
+    def cut(v):
+        bits = lax.bitcast_convert_type(v, jnp.uint32) & jnp.uint32(0xFFFF0000)
+        return lax.bitcast_convert_type(bits, jnp.float32)
+
+    hi = cut(x)
+    mid = cut(x - hi)
+    return jnp.stack([hi, mid, x - hi - mid], -3).astype(jnp.bfloat16)
 
 
 def _run_sums(keys, cols):

@@ -12,6 +12,9 @@ counted by program.
   do not retrace, so a count taken at trace time misses them);
   :func:`cache_hits` counts persistent-cache reads. The listeners are
   registered when this module is imported.
+- :func:`table_readouts` holds, for each trace of the tables' gradient,
+  how many levels its readout searched and how many it wrote (the choice is
+  made from the shapes, at trace time, so no chip is needed to see it).
 """
 from __future__ import annotations
 
@@ -37,6 +40,7 @@ CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _lock = threading.Lock()
 _compiled: list[tuple[str, int, float]] = []    # (name, end time_ns, seconds)
 _cache_hits: list[int] = []                     # time_ns
+_readouts: list[tuple[int, int]] = []           # (levels searched, written)
 
 
 def _on_duration(event: str, duration: float, *, fun_name: str = "", **_):
@@ -81,3 +85,17 @@ def cache_hits(since_ns: int = 0, until_ns=None) -> int:
     within the same bounds as :func:`compile_log`."""
     with _lock:
         return sum(_within(t, since_ns, until_ns) for t in _cache_hits)
+
+
+def record_table_readout(searched: int, written: int) -> None:
+    """Called by the tables' gradient as it is traced: how many of its
+    levels it reads out by a row search and how many by the tile writer."""
+    with _lock:
+        _readouts.append((searched, written))
+
+
+def table_readouts() -> list:
+    """(levels searched, levels written) of each trace of the tables'
+    gradient since this module was imported, oldest first."""
+    with _lock:
+        return list(_readouts)

@@ -5,8 +5,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import tracing
 from repro.kernels.fused_mlp import ref as mlp_ref
 from repro.kernels.fused_mlp.ops import fused_mlp
+from repro.kernels.hash_encoding import ops as he_ops
 from repro.kernels.hash_encoding import ref as he_ref
 from repro.kernels.hash_encoding.ops import hash_encode
 
@@ -104,6 +106,10 @@ _TABLE_GRAD_CASES = {
     "rows_at_batch": (125, 512, (4, 16), 8, jnp.float32, "ref", None),
     "rows_past_batch": (124, 512, (4, 16), 8, jnp.float32, "ref", None),
     "large_table_ranks": (40, 1024, (2, 8, 32), 8, jnp.float32, "fused", 3),
+    # three tiles of the writer; the dense level's and the hashed level's
+    # touched rows run across the tiles' edges
+    "tile_straddle": (300, 3 * he_ops._TILE, (2, 6, 16), 8, jnp.float32,
+                      "ref", None),
 }
 
 
@@ -112,8 +118,9 @@ def test_table_grad_matches_float64_accumulation(case):
     """The tables' gradient (sort, segmented sum, row readout) against a
     float64 scatter-add: dense, hashed and mixed levels, 8N >> T, T = 8N,
     T >> 8N at F = 8, a level's rows in use at and past the batch (searched,
-    then written), rows no sample touches (exactly 0), rank-vmapped calls,
-    bf16 tables, the ref and fused backends."""
+    then written), written rows across the writer's tile edges, rows no
+    sample touches (exactly 0), rank-vmapped calls, bf16 tables, the ref and
+    fused backends."""
     N, T, res, F, dtype, impl, ranks = _TABLE_GRAD_CASES[case]
     P = ranks or 1
     L = len(res)
@@ -129,10 +136,12 @@ def test_table_grad_matches_float64_accumulation(case):
         _, vjp = jax.vjp(lambda t_: hash_encode(c, t_, res, impl), t)
         return vjp(gg)[0]
 
+    traced = len(tracing.table_readouts())
     if ranks:
         got = jax.jit(jax.vmap(table_grad))(coords, tables, g)
     else:
         got = table_grad(coords[0], tables[0], g[0])[None]
+    readouts = tracing.table_readouts()[traced:]
     assert got.dtype == dtype and got.shape == (P, L, T, F)
     got = np.asarray(got.astype(jnp.float32), np.float64)
     rel = 2.0 ** -8 if dtype == jnp.bfloat16 else 0.0
@@ -145,18 +154,92 @@ def test_table_grad_matches_float64_accumulation(case):
         assert np.all(err <= rel * np.abs(want) + 1e-5 * mag + 1e-30), \
             (case, p, float(err.max()))
     # the readout, per level: a search where the rows in use are at most N,
-    # else one scatter of each run's total
+    # else the tile writer; nothing is scattered
     rows = [min((r + 1) ** 3, T) for r in res]
+    written = sum(r > N for r in rows)
+    assert readouts == [(L - written, written)], (case, readouts)
     jaxpr = str(jax.make_jaxpr(table_grad)(coords[0], tables[0], g[0]))
-    assert ("scatter" in jaxpr) == any(r > N for r in rows), case
+    assert "scatter" not in jaxpr, case
     if case.startswith("large_table"):
         assert 8 * N < T and (~touched).any()
         dense = [(r + 1) ** 3 <= T for r in res]
         assert any(dense) and not all(dense)
     if case == "untouched":
         assert (~touched).any()
+    if case == "tile_straddle":
+        B = he_ops._TILE
+        assert written == 2 and all(
+            touched[l, B - 1] and touched[l, B] for l in (1, 2))
+        assert touched[2, 2 * B - 1] and touched[2, 2 * B]
     if case == "duplicated":
         assert 8 * N >= 64 * T
+
+
+def _tile_writer_case(rng, T, M, F, rows_of_level):
+    """keys (L, M): each level's rows, ascending, then T in the places past
+    them; totals (L, F, M) with full 24-bit mantissas, also in those places
+    (there they must be read by no row)."""
+    keys = np.full((len(rows_of_level), M), T, np.int32)
+    for l, rows in enumerate(rows_of_level):
+        keys[l, :len(rows)] = np.sort(rows)
+    mant = rng.integers(2 ** 22, 2 ** 23, (len(rows_of_level), F, M)) | 1
+    totals = (np.float32(2.0 ** -23) * (2 ** 23 + mant).astype(np.float32)
+              * rng.choice(np.float32([-1, 1]), mant.shape)
+              * np.float32(2.0) ** rng.integers(-30, 5, mant.shape))
+    return keys, totals.astype(np.float32)
+
+
+def _assigned(keys, totals, T):
+    """NumPy assignment of each key's totals at its row."""
+    L, F, _ = totals.shape
+    want = np.zeros((L, T, F), np.float32)
+    for l in range(L):
+        ends = keys[l] < T
+        want[l, keys[l, ends]] = totals[l][:, ends].T
+    return want
+
+
+def test_tile_writer_matches_numpy_assignment():
+    """The run totals' tile writer (a one-hot product a tile of rows) puts
+    every total at its row bit for bit and 0 in every other row: a tile
+    with all of its rows, empty tiles, a partial and a last tile, windows
+    that start inside a block of entries, spare places holding totals that
+    no row may read; the totals' mantissas use all 24 bits, so a product
+    that rounds them to bf16 (a TPU's default precision) fails; ranks
+    vmapped."""
+    B = he_ops._TILE
+    T, M, F = 8 * B, 4 * B, 8
+    rng = np.random.default_rng(16)
+    full = np.arange(2 * B, 3 * B)                         # all of tile 2
+    sparse = rng.choice(T, 3 * B, replace=False)
+    levels = [np.concatenate([[B + 3, B + 77], full, [T - 2, T - 1]]),
+              sparse, np.zeros(0, np.int64),
+              rng.choice(np.arange(B // 2, 6 * B), M, replace=False)]
+    keys, totals = _tile_writer_case(rng, T, M, F, levels)
+    assert np.all(totals != totals.astype(jnp.bfloat16).astype(np.float32))
+    first = np.searchsorted(keys[1], np.arange(0, T, B))
+    assert np.any(first % B)                               # mid-block windows
+    write = jax.jit(jax.vmap(he_ops._write_tiles, (0, 0, None)),
+                    static_argnums=2)                      # levels
+    got = np.asarray(write(jnp.asarray(keys), jnp.asarray(totals), T))
+    want = _assigned(keys, totals, T)
+    assert got.shape == want.shape and np.array_equal(got, want)
+    assert not got[0, :B].any() and not got[2].any()       # empty tiles
+    assert np.all(got[0, 2 * B:3 * B] != 0)                # a full tile
+    assert np.all(got[3, :B // 2] == 0) and not got[3, 6 * B:].any()
+    # a partial last tile: its rows past T are cut off
+    short = T - B // 2
+    trimmed = np.where(keys < short, keys, short).astype(np.int32)
+    got = np.asarray(write(jnp.asarray(trimmed), jnp.asarray(totals), short))
+    assert np.array_equal(got, _assigned(trimmed, totals, short))
+    # ranks vmapped, each its own keys
+    keys2, totals2 = _tile_writer_case(
+        rng, T, M, F, [rng.choice(T, n, replace=False) for n in (M, 5, B)])
+    both = np.stack([keys[:3], keys2]), np.stack([totals[:3], totals2])
+    got = np.asarray(jax.jit(jax.vmap(
+        lambda k, t: write(k, t, T)))(*map(jnp.asarray, both)))
+    for p in range(2):
+        assert np.array_equal(got[p], _assigned(both[0][p], both[1][p], T))
 
 
 def test_hash_encode_boundary_coords():

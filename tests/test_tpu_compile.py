@@ -7,8 +7,8 @@ PRODUCTION256 train chunk (8 ranks of 256^3 on one chip), the ABLATION
 train chunk (4 ranks of 512^3 on one chip, within its 16 GB), the 512^2 x
 64 direct frame, the cached service tick and the brick-cache decode; the
 PRODUCTION256 chunk's scan body holds no scatter (the tables' gradient
-sorts and searches each row's run), the ABLATION chunk one scatter a
-table column, of unique, sorted rows (levels with more rows than samples).
+sorts and searches each row's run), nor does the ABLATION chunk (levels
+with more rows than samples are written by tiles, with a one-hot product).
 The ``pallas_tpu`` kernels the compiler refuses are strict xfails carrying
 its refusal, so the change that makes one compile has to flip it — and may
 then let ``auto`` choose it.
@@ -27,6 +27,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import backends
 from repro.configs.dvnr import ABLATION, PRODUCTION256
+from repro.kernels.hash_encoding import ops as he_ops
 
 P = 8                                    # ranks of 256^3 on one chip
 VOLUME = (258, 258, 258)                 # 256^3 owned + 1 ghost layer
@@ -145,10 +146,9 @@ def test_auto_ablation_chunk_fits_one_chip(one_chip, auto_tpu):
     """The ABLATION network's chunk at its published widths, 4 ranks of
     512^3 on one chip (10 x 8 levels, T = 2^19 over 8N = 131,072 corners a
     level): it fits the chip's 16 GB, and its tables' gradient writes the
-    run totals of the levels with more rows than samples with one scatter
-    a table column, of unique, sorted rows. The levels are one flat index,
-    and the compiler folds the vmapped rank into it rank-major, so the
-    rows stay in order: the program holds no sort of its own for them."""
+    run totals of the levels with more rows than samples by tiles, with no
+    scatter: two sorts (the corners', the run ends') and a one-hot product
+    whose one-hot the compiler makes inside the product, never in memory."""
     from repro.core.trainer import DVNRTrainer
 
     tr = DVNRTrainer(ABLATION, 4, impl=auto_tpu, volume_shape=(514,) * 3)
@@ -156,19 +156,20 @@ def test_auto_ablation_chunk_fits_one_chip(one_chip, auto_tpu):
         *_on(one_chip, tr.abstract_chunk_args(4))).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16 * 10**9
-    scatters = [line for line in compiled.as_text().splitlines()
-                if " scatter(" in line]
-    assert len(scatters) == ABLATION.n_features_per_level, scatters
-    for line in scatters:
-        assert "indices_are_sorted=true" in line
-        assert "unique_indices=true" in line
-    # the corners' sort and the run ends' sort, nothing sorted for a scatter
-    assert compiled.as_text().count(" sort(") == 2
-    spare = 2 ** ABLATION.log2_hashmap_size + 8 * ABLATION.batch_size
-    levels = sum((int(ABLATION.base_resolution * ABLATION.per_level_scale ** l)
-                  + 1) ** 3 > ABLATION.batch_size
-                 for l in range(ABLATION.n_levels))
-    assert f"constant({{{levels * spare}, 1}})" in compiled.as_text()
+    text = compiled.as_text()
+    assert " scatter(" not in text
+    assert text.count(" sort(") == 2
+    comps = _computations(text)
+    B = he_ops._TILE
+    fused = {c for body in comps.values()
+             for c in re.findall(r"\bcalls=%([\w.\-]+)", body)}
+    onehots = [line.strip()[:120] for name, body in comps.items()
+               if name not in fused for line in body.splitlines()
+               if re.search(rf"= (pred|bf16)\[[\d,]*,{B},{B}\]", line)]
+    assert not onehots, onehots
+    products = [line for line in text.splitlines() if "convolution(" in line
+                and "table_rows" in line]
+    assert products, "no one-hot product in the tables' gradient"
 
 
 @pytest.mark.parametrize("cached", [False, True], ids=["direct", "cached"])

@@ -4,7 +4,8 @@ Every step path (unfused, fused, fused with in-op sampling) carries the
 stage scopes of :mod:`repro.tracing` into the ``op_name`` of the compiled
 chunk's instructions: each stage is found in the scan's body, the hash
 encode both forward and under ``transpose(`` (the tables' gradient:
-its sort, segmented scan and row readout, with no scatter left).
+its sort, segmented scan and row readout, with no scatter left; where a
+level has more rows than samples, its readout by the tile writer too).
 :func:`repro.tracing.compiles` counts a placement-only recompile, which does
 not retrace.
 """
@@ -50,6 +51,22 @@ def program(request):
     return dispatched, served, text
 
 
+@pytest.fixture(scope="module", params=sorted(PATHS))
+def written_program(request):
+    """(readouts traced, chunk_program's text) at T = 2^8 > 8N = 128 corners
+    a level: every level's readout is the tile writer's."""
+    fuse, sampling = PATHS[request.param]
+    cfg = CFG.replace(batch_size=16, fuse_train_step=fuse,
+                      fuse_sampling=sampling)
+    tr = DVNRTrainer(cfg, 2, impl="ref", volume_shape=(10, 10, 10))
+    vols = jnp.linspace(0, 1, 2000, dtype=jnp.float32).reshape(2, 10, 10, 10)
+    state = tr.init(jax.random.PRNGKey(0))
+    traced = len(tracing.table_readouts())
+    text = tr.chunk_program(state, vols, 2,
+                            key=jnp.asarray([3, 4], jnp.uint32)).as_text()
+    return tracing.table_readouts()[traced:], text
+
+
 def _op_names(text):
     return re.findall(r'op_name="([^"]*)"', text)
 
@@ -87,6 +104,29 @@ def test_table_grad_runs_under_the_encode_transpose_without_scatter(program):
     assert sorts and all(_innermost(op) == (tracing.ENCODE, True)
                          for op in sorts), sorts
     assert not re.search(r"= \S+ scatter\(", text)
+
+
+def test_tile_writer_runs_under_the_encode_transpose_without_scatter(
+        written_program):
+    """A level with more rows in use than samples is read out by the tile
+    writer: its second sort, window gathers and one-hot product all carry
+    ``table_rows`` inside the encode's transpose, so the stage readers count
+    them as ``table_grad`` and ``table_rows``; nothing is scattered."""
+    readouts, text = written_program
+    assert readouts and set(readouts) == {(0, CFG.n_levels)}, readouts
+    assert not re.search(r"= \S+ scatter\(", text)
+    body = [op for op in _op_names(text) if "/while/body/" in op]
+    rows = [op for op in body if tracing.TABLE_ROWS in op]
+    kinds = {op.rsplit("/", 1)[-1] for op in rows}
+    assert {"sort", "gather", "dot_general", "eq"} <= kinds, kinds
+    for op in rows:
+        assert _innermost(op) == (tracing.ENCODE, True), op
+        encode = op.index(tracing.ENCODE)
+        assert f"/{tracing.TABLE_ROWS}/" in op[encode:], op
+    # the product is the writer's: no dot of the tables' gradient outside it
+    grad = [op for op in body if _innermost(op) == (tracing.ENCODE, True)]
+    assert all(tracing.TABLE_ROWS in op for op in grad
+               if op.endswith("dot_general")), grad
 
 
 def test_no_instruction_names_a_scope_outside_the_list(program):
